@@ -25,8 +25,8 @@ single-device engine boundary the batcher already owns
 (``_encode_sync`` / ``_decode_sync`` and the ``_dispatch_block``
 row-block closures of the over-decomposed dispatch — their mesh
 siblings are NOT sanctioned, they must route through the view
-reader), and the two host-side helpers that touch device lists, not
-data (``make_mesh``, ``_platform_healthy``).
+reader), and the host-side helper that touches device lists, not
+data (``make_mesh``).
 """
 from __future__ import annotations
 
@@ -42,7 +42,7 @@ _SANCTIONED = frozenset((
     "shard_rows_to_host", "host_gather",
     "_encode_sync", "_decode_sync", "_repair_sync",
     "_dispatch_block",
-    "make_mesh", "_platform_healthy",
+    "make_mesh",
 ))
 
 _MSG_DEVICE_GET = (

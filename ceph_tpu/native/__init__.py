@@ -7,7 +7,6 @@ conventions of ceph_tpu.ops (chunks are row-major (k, L) uint8).
 from __future__ import annotations
 
 import ctypes
-import os
 import subprocess
 from pathlib import Path
 
@@ -21,16 +20,32 @@ _u32p = np.ctypeslib.ndpointer(dtype=np.uint32, flags="C_CONTIGUOUS")
 _i32p = np.ctypeslib.ndpointer(dtype=np.int32, flags="C_CONTIGUOUS")
 
 
+def build_lib(so: Path, srcs: list[Path]) -> None:
+    """(Re)build ``so`` from its committed sources when it is missing or
+    older than any of them. Neither library is committed: each checkout
+    builds its own on first import. The check and the build hold one
+    file lock, so concurrent importers (pytest-xdist workers) never see
+    a half-linked library."""
+    import fcntl
+
+    with open(_DIR / ".build.lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if so.exists() and all(so.stat().st_mtime >= s.stat().st_mtime
+                               for s in srcs):
+            return
+        try:
+            subprocess.run(["make", "-C", str(_DIR), so.name], check=True,
+                           capture_output=True)
+        except subprocess.CalledProcessError as e:
+            raise RuntimeError(
+                f"building {so.name} failed:\n"
+                f"{e.stderr.decode(errors='replace')}"
+            ) from e
+
+
 def _build() -> None:
-    srcs = [_DIR / "ct_native.cc", _DIR / "gen_tables.py", _DIR / "Makefile"]
-    if _SO.exists() and all(_SO.stat().st_mtime >= s.stat().st_mtime for s in srcs):
-        return
-    try:
-        subprocess.run(["make", "-C", str(_DIR)], check=True, capture_output=True)
-    except subprocess.CalledProcessError as e:
-        raise RuntimeError(
-            f"building libceph_tpu_native failed:\n{e.stderr.decode(errors='replace')}"
-        ) from e
+    build_lib(_SO, [_DIR / "ct_native.cc", _DIR / "gen_tables.py",
+                    _DIR / "Makefile"])
 
 
 def _load() -> ctypes.CDLL:

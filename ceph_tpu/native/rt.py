@@ -7,7 +7,6 @@ from __future__ import annotations
 
 import ctypes
 import struct
-import subprocess
 from pathlib import Path
 
 _DIR = Path(__file__).resolve().parent
@@ -17,17 +16,9 @@ _u8p = ctypes.POINTER(ctypes.c_uint8)
 
 
 def _build() -> None:
-    src = _DIR / "rt_native.cc"
-    if _SO.exists() and _SO.stat().st_mtime >= src.stat().st_mtime:
-        return
-    try:
-        subprocess.run(["make", "-C", str(_DIR), _SO.name], check=True,
-                       capture_output=True)
-    except subprocess.CalledProcessError as e:
-        raise RuntimeError(
-            f"building {_SO.name} failed:\n"
-            f"{e.stderr.decode(errors='replace')}"
-        ) from e
+    from . import build_lib
+
+    build_lib(_SO, [_DIR / "rt_native.cc", _DIR / "Makefile"])
 
 
 def _load() -> ctypes.CDLL:

@@ -36,15 +36,9 @@ _U32 = jnp.uint32
 _I64 = jnp.int64
 INT64_MIN = -(1 << 63)
 
-# jax.enable_x64 (the top-level alias) was removed upstream; the
-# experimental home has carried the context manager across every jax
-# this repo supports, so resolve it once here and let the rest of the
-# tree import THIS symbol (ops.crush.enable_x64) instead of racing
-# jax's deprecation shims.
-try:
-    enable_x64 = jax.enable_x64
-except AttributeError:  # newer jax: experimental home only
-    from jax.experimental import enable_x64
+#: the scoped 64-bit context manager; the rest of the tree imports
+#: THIS symbol (ops.crush.enable_x64)
+enable_x64 = jax.enable_x64
 
 
 def _x64(fn):

@@ -268,6 +268,10 @@ def gf_matmul_pallas(matrix: np.ndarray, chunks: jax.Array,
     the reference MXU formulation and for codes wide enough to fill
     the array; `auto` resolves to SWAR on TPU (ErasureCodeIsa.cc:120
     ec_encode_data is the host analog of that choice).
+
+    ``interpret`` comes from the caller only: off the TPU, a call
+    without ``interpret=True`` fails to lower rather than quietly
+    running the Pallas interpreter.
     """
     rows, cols = matrix.shape
     if chunks.shape[-2] != cols:
@@ -308,8 +312,7 @@ def _partitioned_gf_pallas(rows: int):
 
     @custom_partitioning
     def fn(x3, bm):
-        return _gf_pallas_raw(x3, bm, rows,
-                              interpret=jax.default_backend() != "tpu")
+        return _gf_pallas_raw(x3, bm, rows)
 
     def _shardings(mesh, arg_shapes):
         spec = arg_shapes[0].sharding.spec
@@ -326,21 +329,13 @@ def _partitioned_gf_pallas(rows: int):
         x_sh, bm_sh = _shardings(mesh, arg_shapes)
 
         def lower_fn(x3, bm):
-            return _gf_pallas_raw(x3, bm, rows,
-                                  interpret=jax.default_backend() != "tpu")
+            return _gf_pallas_raw(x3, bm, rows)
 
         return mesh, lower_fn, x_sh, (x_sh, bm_sh)
 
-    try:
-        fn.def_partition(infer_sharding_from_operands=infer,
-                         partition=partition,
-                         sharding_rule="b c w, rr cc -> b r w")
-    except TypeError:
-        # older jax: def_partition has no sharding_rule (the einsum-
-        # notation hint for shardy); the callback pair alone carries
-        # the GSPMD lowering there
-        fn.def_partition(infer_sharding_from_operands=infer,
-                         partition=partition)
+    fn.def_partition(infer_sharding_from_operands=infer,
+                     partition=partition,
+                     sharding_rule="b c w, rr cc -> b r w")
     _PARTITIONED_GF_PALLAS[rows] = fn
     return fn
 

@@ -5,11 +5,9 @@ The ECBatcher (cluster/ecbatch.py) talks to the mesh exclusively
 through this module:
 
 - :func:`serving_mesh` resolves the configured device mesh once per
-  process and degrades GRACEFULLY to ``None`` (the single-device path)
-  when the platform cannot supply the devices — a laptop, a 1-chip
-  host, a container without the forced-CPU flags. The cluster must
-  keep serving either way; the mesh is a throughput lever, never a
-  liveness dependency.
+  process. A platform that cannot supply the configured devices is an
+  error, not a quiet single-device run; ``osd_ec_mesh_devices <= 1``
+  is how a deployment asks for the single-device path.
 - :func:`mesh_encode_crc_batch` runs the fused encode+CRC program
   jitted UNDER the mesh: stripe batches are staged device-resident
   (``chunk_batch_sharding`` — batch over ``stripe``, chunk words over
@@ -122,19 +120,16 @@ _dispatch_lock = threading.Lock()
 
 def serving_mesh(n_devices: int, width: int = 1):
     """The (stripe, width) mesh the OSD serving path runs on, or
-    ``None`` when the PLATFORM cannot provide ``n_devices`` working
-    devices (or the config disables the mesh with n_devices <= 1).
+    ``None`` when the config disables the mesh (n_devices <= 1).
 
-    A width that does not divide the device count is a CONFIG error
-    and raises — degrading it silently would report an all-zero mesh
-    ledger from a run the operator asked to shard (the thrash verdict
-    and bench config 8 would claim a mesh run that never meshed).
-    Only genuine platform failures degrade to the 1-device path.
+    A mesh the operator configured is served or refused, never
+    degraded: a width that does not divide the device count, or a
+    platform with fewer than ``n_devices`` devices, raises — serving
+    single-device instead would report an all-zero mesh ledger from a
+    run the operator asked to shard.
 
     Resolution is cached per (n, width) and shared by every OSD in the
-    process — chips are a host resource, not a daemon one. Platform
-    failure is cached too: probing a broken accelerator plugin once
-    per dispatch would stall the data path."""
+    process — chips are a host resource, not a daemon one."""
     if n_devices <= 1 or width < 1:
         return None
     if n_devices % width:
@@ -144,11 +139,7 @@ def serving_mesh(n_devices: int, width: int = 1):
     key = (int(n_devices), int(width))
     with _mesh_lock:
         if key not in _meshes:
-            try:
-                devs = get_devices(key[0])
-                _meshes[key] = make_mesh(devs, width=key[1])
-            except Exception:
-                _meshes[key] = None
+            _meshes[key] = make_mesh(get_devices(key[0]), width=key[1])
         return _meshes[key]
 
 

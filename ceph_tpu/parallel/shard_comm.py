@@ -32,12 +32,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from jax import shard_map  # jax >= 0.7 home
-    _SM_NOCHECK = {"check_vma": False}
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
-    _SM_NOCHECK = {"check_rep": False}
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops import gf8, rs
@@ -106,7 +101,7 @@ def _jit_distributed_matmul(mesh: Mesh, matrix_bytes: bytes, rows: int,
         local_fn, mesh=mesh,
         in_specs=(P(), shard_placement_spec()),
         out_specs=P(STRIPE_AXIS, None, None),
-        **_SM_NOCHECK,
+        check_vma=False,
     )
     return jax.jit(functools.partial(fn, bm_blocks))
 

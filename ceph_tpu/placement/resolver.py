@@ -7,8 +7,8 @@ dispatch to recomputing host straw2 for it.  Within an epoch CRUSH is a
 pure function of the map, so the resolver memoizes results EPOCH-KEYED
 (one dict hit per op in steady state, invalidated wholesale the instant
 the map moves) and resolves misses through the device bulk-CRUSH engine
-(placement/bulk.py, the north-star config-5 kernel: 0.31 Mobj/s over
-1 K OSDs on the stand-in, 13.9x host) in coalesced batches behind the
+(placement/bulk.py, the north-star config-5 kernel; its device rate is
+not measured on this code) in coalesced batches behind the
 same window/size trigger discipline the ECBatcher uses: misses arriving
 within ``client_placement_batch_window`` seconds — or until
 ``client_placement_batch_target`` pgids are queued — ride ONE device
@@ -19,12 +19,13 @@ Placement is never a liveness dependency:
 - the sync surface (``up_acting``/``full``) serves hits from the memo
   and misses from the host pipeline immediately — it is the drop-in
   replacement for the old ``PlacementMemo`` and what daemons use;
-- the async surface parks misses on the coalescing window, but any
-  wrinkle — unsupported map shape (``CompiledMap`` rejects it), a
-  dead/missing accelerator, an epoch that moved mid-dispatch, a batch
-  below ``client_placement_batch_min`` (a cold jit compile would cost
-  more than it saves, the DEVICE_MIN_BYTES stance) — falls back to the
-  host pipeline for exactly the affected waiters;
+- the async surface parks misses on the coalescing window, but an
+  unsupported map shape (``CompiledMap`` rejects it), an epoch that
+  moved mid-dispatch, or a batch below ``client_placement_batch_min``
+  (a cold jit compile would cost more than it saves, the
+  DEVICE_MIN_BYTES stance) serves the affected waiters from the host
+  pipeline. A device that fails a dispatch is an error for exactly
+  those waiters, never a quiet switch to the host;
 - ``CEPH_TPU_PLACEMENT_BATCH=0`` is the A/B lever: the async surface
   becomes pure memo+host, so a bench pair attributes the win.
 
@@ -64,12 +65,6 @@ def _load_bulk():
         bulk = _bulk
     return bulk
 
-#: process-sticky "the device engine is broken here" latch: one failed
-#: dispatch (missing/poisoned jax) must not be re-discovered by every
-#: resolver instance in the process
-_DEVICE_BROKEN = False
-
-
 def _batch_enabled() -> bool:
     return os.environ.get("CEPH_TPU_PLACEMENT_BATCH", "1") != "0"
 
@@ -80,7 +75,7 @@ class _MapCompile:
     a rejection (unsupported shape) so it is not re-attempted."""
 
     __slots__ = ("crush", "compiled", "rejected", "warm", "warming",
-                 "cold_seen")
+                 "cold_seen", "failed")
 
     def __init__(self, crush):
         self.crush = crush
@@ -91,6 +86,9 @@ class _MapCompile:
         #: compile (~1 s on the CPU stand-in) must never stall parked
         #: ops, so cold flushes host-serve and warm in the background
         self.warm: set[tuple] = set()
+        #: the error a background warm dispatch raised: the next batch
+        #: against this map fails with it instead of host-serving
+        self.failed: BaseException | None = None
         #: combos with a background warm in flight (dedup)
         self.warming: set[tuple] = set()
         #: cold miss-storms seen per combo: the background warm only
@@ -359,7 +357,6 @@ class PlacementResolver:
         return entry.compiled
 
     async def _run_batch(self, pool_id: int, items: list) -> None:
-        global _DEVICE_BROKEN
         # one flush can hold entries against different map objects
         # (client reconnect churn); group them
         by_map: dict[int, list] = {}
@@ -369,7 +366,7 @@ class PlacementResolver:
         for group in by_map.values():
             osdmap = group[0][0]
             pool = osdmap.pools.get(pool_id)
-            compiled = (None if pool is None or _DEVICE_BROKEN
+            compiled = (None if pool is None
                         else self._compile_for(osdmap.crush))
             # dedup pgids: N waiters for one pgid cost one lane
             pgids = sorted({pgid for _m, pgid, _f in group})
@@ -377,6 +374,9 @@ class PlacementResolver:
                 self._resolve_host(group)
                 continue
             entry = self._compiles[id(osdmap.crush)]
+            if entry.failed is not None:
+                self._fail(group, entry.failed)
+                continue
             key = (pool.crush_rule, pool.size,
                    _pad_len(len(pgids), self._target()))
             if key not in entry.warm:
@@ -400,19 +400,16 @@ class PlacementResolver:
                     self._kick_warm(entry, osdmap, pool, key)
                 continue
             epoch0 = osdmap.epoch
-            rows = None
             try:
                 rows = await self._device_rows(osdmap, pool, compiled,
                                                pgids)
-            except Exception:
-                _DEVICE_BROKEN = True  # fail once per process, loudly
-                import traceback
-
-                traceback.print_exc()
-            if (rows is None or osdmap.epoch != epoch0
+            except Exception as e:
+                self._fail(group, e)
+                continue
+            if (osdmap.epoch != epoch0
                     or self._map is not osdmap
                     or self._epoch != epoch0):
-                # engine trouble, the epoch moved mid-dispatch, or the
+                # the epoch moved mid-dispatch, or the
                 # resolver has seen a DIFFERENT map object since this
                 # batch was queued (a mon gap-fill REPLACES the map
                 # wholesale, so its epoch alone can't witness the
@@ -442,7 +439,7 @@ class PlacementResolver:
         off the op path: a throwaway dispatch of the exact shape later
         batches will use (inputs are irrelevant to the jit cache, the
         weights VECTOR LENGTH is part of the shape). Marks the combo
-        warm on success; failure trips the process device latch."""
+        warm on success; a failure fails the next batch on this map."""
         if key in entry.warming or key in entry.warm:
             return
         entry.warming.add(key)
@@ -453,16 +450,12 @@ class PlacementResolver:
         loop = asyncio.get_running_loop()
 
         async def warm() -> None:
-            global _DEVICE_BROKEN
             try:
                 await loop.run_in_executor(
                     None, bulk.do_rule_bulk, entry.compiled, ruleno,
                     xs, numrep, weights)
-            except Exception:
-                _DEVICE_BROKEN = True
-                import traceback
-
-                traceback.print_exc()
+            except Exception as e:
+                entry.failed = e
             else:
                 entry.warm.add(key)
                 self.stats.placement_bg_warms += 1
@@ -470,6 +463,12 @@ class PlacementResolver:
                 entry.warming.discard(key)
 
         loop.create_task(warm())
+
+    @staticmethod
+    def _fail(group: list, exc: BaseException) -> None:
+        for _m, _pgid, fut in group:
+            if not fut.done():
+                fut.set_exception(exc)
 
     def _resolve_host(self, group: list) -> None:
         for osdmap, pgid, fut in group:
@@ -519,10 +518,10 @@ class PlacementResolver:
         """Compile the bulk engine and device-resolve EVERY pgid of
         the given pools — the serving-process startup warm (config 10
         calls it before the measured phase so cold jit compiles never
-        ride a client op). Returns the number of pgids resolved; 0
-        when the device path is unavailable (host serves, as always).
+        ride a client op). Returns the number of pgids resolved (0
+        with batching off); a device error raises.
         """
-        if not self._batch or _DEVICE_BROKEN:
+        if not self._batch:
             return 0
         self._sync_epoch(osdmap)
         warmed = 0
@@ -542,11 +541,8 @@ class PlacementResolver:
                 chunk = all_pgids[lo: lo + target]
                 self._sync_epoch(osdmap)  # adopt bumps between chunks
                 epoch0 = osdmap.epoch
-                try:
-                    rows = await self._device_rows(osdmap, pool,
-                                                   compiled, chunk)
-                except Exception:
-                    break
+                rows = await self._device_rows(osdmap, pool, compiled,
+                                               chunk)
                 entry.warm.add((pool.crush_rule, pool.size,
                                 _pad_len(len(chunk), target)))
                 if (self._map is not osdmap

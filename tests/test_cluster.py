@@ -342,7 +342,7 @@ def test_mark_out_replaces_member():
 
 
 def test_primary_crash_mid_fanout_survivors_converge():
-    """VERDICT r3 #6: kill the primary after SOME (not all) replicas
+    """round-3 review #6: kill the primary after SOME (not all) replicas
     committed a rep-op. The unacked entry lives on one survivor only;
     the new interval must converge both survivors to one authoritative
     state, the client's resend must land exactly once, and a scrub must

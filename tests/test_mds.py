@@ -1,7 +1,7 @@
 """MDSLite daemon: metadata authority, capabilities with revoke, and
 MDLog-role journal recovery.
 
-Acceptance (VERDICT r2 item 7): a two-client coherence test and a
+Acceptance (round-2 review item 7): a two-client coherence test and a
 kill-MDS-mid-rename recovery test.
 """
 import asyncio
@@ -213,7 +213,7 @@ def test_dead_client_evicted():
 
 def test_fs_snapshots_read_back_after_mutation():
     """.snap-role read-only snapshots (SnapServer + snaprealm roles,
-    VERDICT r4 #8): metadata freezes at mksnap, file DATA is lazy-COW
+    round-4 review #8): metadata freezes at mksnap, file DATA is lazy-COW
     through the data pool's SnapContext — overwrite, truncate, delete,
     and new files after the snapshot never leak into it."""
     async def t():

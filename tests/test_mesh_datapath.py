@@ -5,8 +5,8 @@ Unit tier pins the acceptance contract directly: mesh-sharded fused
 encode+CRC and collective repair are BYTE-IDENTICAL to the
 single-device dispatch over random stripes, results cross to the host
 only as per-device shard views (host_gathers stays 0), occupancy lands
-evenly across chips, and a platform that cannot supply the mesh
-degrades gracefully to the 1-device path. Cluster tier proves OSD
+evenly across chips, and a platform that cannot supply the configured
+mesh is an error, never a quiet 1-device run. Cluster tier proves OSD
 traffic actually crosses the mesh: a live TestCluster with the mesh
 knobs on serves writes through sharded dispatches and a degraded read
 through the collective repair path. Everything runs on the 8-device
@@ -119,19 +119,20 @@ def test_mesh_single_stripe_pads_to_stripe_row():
     run(t())
 
 
-def test_mesh_unavailable_degrades_to_single_device():
-    """A config asking for more devices than the platform has must NOT
-    break serving: the batcher falls back to the 1-device dispatch."""
+def test_mesh_unavailable_fails_the_dispatch():
+    """A config asking for more devices than the platform has fails the
+    dispatch: serving single-device instead would report an all-zero
+    mesh ledger from a run the operator asked to shard."""
     codec = load_codec(dict(DEV_PROFILE))
     cells = rand_cells(4, seed=4)
+    runtime.reset_meshes()
 
     async def t():
-        degraded = ECBatcher(conf=mesh_conf(n=4096))
-        single = ECBatcher()
-        pd, cd = await degraded.encode_cells(codec, cells)
-        ps, cs = await single.encode_cells(codec, cells)
-        assert degraded.mesh() is None
-        assert (pd == ps).all() and (cd == cs).all()
+        b = ECBatcher(conf=mesh_conf(n=4096))
+        with pytest.raises(RuntimeError, match="dispatch failed"):
+            await b.encode_cells(codec, cells)
+        with pytest.raises(RuntimeError, match="need 4096 devices"):
+            b.mesh()
 
     run(t())
 

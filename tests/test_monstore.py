@@ -1,6 +1,6 @@
 """Durable mon: MonitorDBStore-role persistence on the native kv.
 
-Acceptance (VERDICT r2 item 5): kill all mons+OSDs, restart from disk,
+Acceptance (round-2 review item 5): kill all mons+OSDs, restart from disk,
 and the cluster converges with its maps, pools, config DB, and epochs
 intact — no pool re-creation, no data loss.
 """

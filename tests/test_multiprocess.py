@@ -1,5 +1,5 @@
 """Multi-process cluster tier: mon + OSDs as separate OS processes over
-real TCP sockets (the vstart.sh + qa/standalone role — VERDICT r3 #1).
+real TCP sockets (the vstart.sh + qa/standalone role — round-3 review #1).
 
 What this tier proves that the in-process tier cannot: the wire is real
 (kernel sockets, process isolation), kill -9 is a REAL crash (the
@@ -150,7 +150,7 @@ def test_multiprocess_cephx_secure(tmp_path):
 
 
 def test_multiprocess_mon_leader_kill9(tmp_path):
-    """Paxos over real sockets (VERDICT r4 #3): kill -9 the LEADER mon
+    """Paxos over real sockets (round-4 review #3): kill -9 the LEADER mon
     process mid-write-stream. The quorum re-elects, the public "mon"
     book alias hands over, in-flight IO completes, failure adjudication
     (an OSD kill) still commits new map epochs, and the revived mon
@@ -246,7 +246,7 @@ def test_multiprocess_mon_peon_kill9(tmp_path):
 
 
 def test_multiprocess_entity_auth_blocks_impersonation(tmp_path):
-    """Per-entity wire auth (VERDICT r4 #5): a rogue process that holds
+    """Per-entity wire auth (round-4 review #5): a rogue process that holds
     ONLY the shared node key (so it passes the connection handshake)
     must not be able to speak AS "mon" — neither through the API (no
     signing key) nor by forging an envelope signed with the node key

@@ -14,7 +14,6 @@ import pytest
 
 from ceph_tpu.placement import bulk
 from ceph_tpu.placement import crushmap as cm
-from ceph_tpu.placement import resolver as rmod
 from ceph_tpu.placement.osdmap import Incremental, OSDMap, Pool
 from ceph_tpu.placement.resolver import PlacementResolver
 from ceph_tpu.utils import config as cfg
@@ -149,29 +148,27 @@ def test_epoch_bump_mid_window_resolves_on_current_map():
     asyncio.run(run())
 
 
-def test_device_failure_falls_back_to_host(monkeypatch):
+def test_device_failure_fails_the_waiters(monkeypatch):
+    """A failing device is an error for the waiters of that batch, not
+    a quiet switch to host placement."""
     async def run():
         om = _map()
         r = PlacementResolver(conf=_conf(), batch=True)
+        assert await r.prewarm(om, [1]) > 0  # warm: storms dispatch
+        om.apply_incremental(Incremental(epoch=2))  # drop the memo
 
         def boom(*a, **kw):
             raise RuntimeError("no accelerator")
 
         monkeypatch.setattr(bulk, "do_rule_bulk", boom)
         got = await asyncio.gather(*(r.afull(om, (1, ps))
-                                     for ps in range(32)))
-        for ps, g in enumerate(got):
-            want = om.pg_to_up_acting_full((1, ps))
-            assert _full_tuple(g) == _full_tuple(want)
-        assert r.stats.placement_batch_lookups == 0
-        assert r.stats.placement_host_resolves >= 32
+                                     for ps in range(32)),
+                                   return_exceptions=True)
+        assert all(isinstance(g, RuntimeError) for g in got), got
+        with pytest.raises(RuntimeError, match="no accelerator"):
+            await r.prewarm(om, [1])
 
-    saved = rmod._DEVICE_BROKEN
-    try:
-        asyncio.run(run())
-    finally:
-        # the sticky process latch must not poison later tests
-        rmod._DEVICE_BROKEN = saved
+    asyncio.run(run())
 
 
 def test_unsupported_map_rejected_once_host_serves():

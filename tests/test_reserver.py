@@ -1,4 +1,4 @@
-"""Backfill reservations (AsyncReserver role, VERDICT r3 #7): recovery
+"""Backfill reservations (AsyncReserver role, round-3 review #7): recovery
 concurrency is bounded per OSD while client IO keeps flowing."""
 import asyncio
 

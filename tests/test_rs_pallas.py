@@ -43,8 +43,18 @@ def test_pallas_unaligned_width_falls_back():
     mat = native.rs_matrix_vandermonde(3, 2)
     data = rng.integers(0, 256, (3, 400), dtype=np.uint8)
     want = gf8.gf_matmul(mat, data)
-    got = rs.gf_matmul_pallas(mat, jnp.asarray(rs.pack_u32(data)))
+    got = rs.gf_matmul_pallas(mat, jnp.asarray(rs.pack_u32(data)),
+                              interpret=True)
     assert (rs.unpack_u32(np.asarray(got)) == want).all()
+
+
+def test_pallas_off_tpu_without_interpret_is_an_error():
+    """The caller picks interpret mode; off the TPU, the kernel refuses
+    to lower instead of quietly running the interpreter."""
+    mat = native.rs_matrix_vandermonde(3, 2)
+    data = np.zeros((3, 512), dtype=np.uint8)
+    with pytest.raises(Exception, match="interpret"):
+        rs.gf_matmul_pallas(mat, jnp.asarray(rs.pack_u32(data)))
 
 
 def test_lift_bitmatrix_planar_permutation():

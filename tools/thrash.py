@@ -132,10 +132,9 @@ def main(argv: list[str] | None = None) -> int:
         return 0
 
     if args.chip_loss:
-        # the mesh must exist BEFORE any jax backend init: force the
-        # virtual host platform to the chip count (the CPU recipe the
-        # mesh tests use; on a real multi-chip host get_devices picks
-        # the healthy accelerator platform instead)
+        # the mesh must exist BEFORE any jax backend init: ask for the
+        # virtual host platform at the chip count explicitly (the CPU
+        # recipe the mesh tests use; nothing falls back to it)
         from ceph_tpu import parallel
 
         parallel.pin_virtual_cpu(args.chips)
