@@ -387,8 +387,7 @@ class RadosClient:
         verb = ops[0][0] if ops else "noop"
         seq, snap_list = snapc if snapc else (0, [])
         with self._tracer.start_span(verb) as span:
-            span.tag("pgid", pgid).tag("oid",
-                                       oid[:64].decode(errors="replace"))
+            span.tag("pgid", pgid).tag("oid", oid)
             # placement FIRST (batched: concurrent ops' misses share
             # one device dispatch), then stamp the epoch — the window
             # may have spanned a map change and the op must carry the

@@ -43,17 +43,26 @@ codecs from the same profile must share a bucket anyway.
 
 Perf counters (declared by :meth:`ECBatcher.declare_counters`) record
 batch occupancy, flush reason, queue wait, and failures, so the bench
-can report WHY batches are the size they are.
+can report WHY batches are the size they are — and where a dispatch's
+time goes: every dispatch runs in timed worker-thread stages, each a
+profiler span (utils/trace.host_span) that lands in a device trace on
+the device's clock: ``ec.stage`` (pack, pow2 pad, device_put, the
+jitted call returning), ``ec.device_wait`` (block_until_ready),
+``ec.readback`` (device-to-host) and ``ec.unpack``; ``ec.host`` is the
+host engine's whole native call. The handoff between the loop and the
+worker thread is timed on ``time.perf_counter_ns`` from both sides.
 """
 from __future__ import annotations
 
 import asyncio
 import os
 import threading
+import time
 
 import numpy as np
 
 from .. import native
+from ..utils import trace
 from ..utils.fault import InjectedError
 
 _FAILED = object()
@@ -77,6 +86,57 @@ FLUSH_REASONS = ("size", "deadline", "fast", "tick", "drain")
 #: never pay the compile. Override: osd_ec_cold_shape_bytes (0
 #: disables the shield).
 COLD_SHAPE_BYTES = 256 << 20
+
+
+class _StageClock:
+    """Worker-thread stage times (ns) of one dispatch, flushed into the
+    batcher's time_avg counters once per dispatch on the loop. Over-
+    decomposed row blocks add here from several threads at once
+    (list.append is atomic), so their sums are thread time."""
+
+    __slots__ = ("host", "device")
+
+    def __init__(self) -> None:
+        self.host: list[int] = []
+        #: (device_wait, readback) per device-engine block
+        self.device: list[tuple[int, int]] = []
+
+    def add(self, host_ns: int, wait_ns: int | None = None,
+            readback_ns: int = 0) -> None:
+        self.host.append(host_ns)
+        if wait_ns is not None:
+            self.device.append((wait_ns, readback_ns))
+
+    def flush(self, perf) -> None:
+        if self.host:
+            perf.tinc("ec_host_lat", sum(self.host) * 1e-9)
+        if self.device:
+            perf.tinc("ec_device_wait_lat",
+                      sum(w for w, _ in self.device) * 1e-9)
+            perf.tinc("ec_readback_lat",
+                      sum(r for _, r in self.device) * 1e-9)
+
+
+#: the stage clock of the dispatch the current worker thread runs
+_worker = threading.local()
+
+
+def _stage_clock() -> _StageClock:
+    """This dispatch's clock (a throwaway one outside _worker_call)."""
+    clock = getattr(_worker, "clock", None)
+    return clock if clock is not None else _StageClock()
+
+
+def _worker_call(fn, *args):
+    """The executor side of one dispatch: run ``fn`` under a fresh
+    stage clock; returns (result, clock, start ns, return ns)."""
+    t_start = time.perf_counter_ns()
+    clock = _worker.clock = _StageClock()
+    try:
+        out = fn(*args)
+    finally:
+        _worker.clock = None
+    return out, clock, t_start, time.perf_counter_ns()
 
 
 def codec_profile_key(codec) -> tuple:
@@ -185,6 +245,22 @@ class ECBatcher:
                            "stripes per EC decode batch")
         perf.add_histogram("ec_queue_wait_us",
                            "per-stripe-group wait in the batch queue (us)")
+        # one sample per successful dispatch (host_lat on either
+        # engine, device_wait/readback on the device engine only)
+        perf.add_time_avg("ec_host_lat",
+                          "dispatch host work on the worker thread: "
+                          "ec.stage + ec.unpack, or the host engine's "
+                          "whole native call")
+        perf.add_time_avg("ec_device_wait_lat",
+                          "dispatch wait for the device outputs "
+                          "(ec.device_wait)")
+        perf.add_time_avg("ec_readback_lat",
+                          "dispatch device-to-host readback of ready "
+                          "outputs (ec.readback)")
+        perf.add_time_avg("ec_handoff_lat",
+                          "dispatch loop<->worker handoff: executor "
+                          "start delay plus the lag from the worker "
+                          "returning to the loop resuming")
         for reason in FLUSH_REASONS:
             perf.add_u64_counter(f"ec_flush_{reason}",
                                  f"EC batch flushes triggered by {reason}")
@@ -416,13 +492,20 @@ class ECBatcher:
                 "ec_batch", kind=key[0], stripes=len(cells)):
             raise InjectedError("injected EC batch dispatch failure")
         if key[0] == "enc":
-            return await loop.run_in_executor(
-                None, self._encode_sync, codec, cells)
-        if key[0] == "rep":
-            return await loop.run_in_executor(
-                None, self._repair_sync, codec, key[3], key[4], cells)
-        return await loop.run_in_executor(
-            None, self._decode_sync, codec, key[3], key[4], cells)
+            fn, args = self._encode_sync, (codec, cells)
+        elif key[0] == "rep":
+            fn, args = self._repair_sync, (codec, key[3], key[4], cells)
+        else:
+            fn, args = self._decode_sync, (codec, key[3], key[4], cells)
+        t_sub = time.perf_counter_ns()
+        out, clock, t_start, t_ret = await loop.run_in_executor(
+            None, _worker_call, fn, *args)
+        if self.perf is not None:
+            self.perf.tinc("ec_handoff_lat",
+                           (t_start - t_sub + time.perf_counter_ns()
+                            - t_ret) * 1e-9)
+            clock.flush(self.perf)
+        return out
 
     def _count_cause(self, exc: BaseException) -> None:
         if self.perf is not None:
@@ -545,39 +628,71 @@ class ECBatcher:
         pad = np.zeros((target - n,) + batch.shape[1:], dtype=batch.dtype)
         return np.concatenate([batch, pad])
 
+    @staticmethod
+    def _device_stages(clock: _StageClock, stage, wait, fetch, finish):
+        """One device-engine dispatch in its four timed worker stages:
+        ``stage()`` stages the batch and launches the program,
+        ``wait`` blocks on its outputs, ``fetch`` reads them back and
+        ``finish`` unpacks and slices; each step's value feeds the
+        next. Each stage is a profiler span and a clock sample."""
+        with trace.host_span("ec.stage") as st:
+            x = stage()
+        with trace.host_span("ec.device_wait") as dw:
+            x = wait(x)
+        with trace.host_span("ec.readback") as rb:
+            x = fetch(x)
+        with trace.host_span("ec.unpack") as up:
+            x = finish(x)
+        clock.add(st.ns + up.ns, dw.ns, rb.ns)
+        return x
+
     def _encode_sync(self, codec, cells: np.ndarray):
         """(B, k, su) u8 -> (parity (B, m, su) u8, crcs | None)."""
         engine = getattr(codec, "resolved_backend", lambda: "device")()
         b, k, su = cells.shape
+        clock = _stage_clock()
         if engine == "host" or not hasattr(codec, "encode_crc_batch"):
-            if getattr(codec, "bytewise_linear", False):
-                # GF(2^8) matrix codes: ONE multithreaded C++ matmul
-                # over the shard-major flatten (reads the RMW staging
-                # buffer's contiguous storage back without a copy)
-                flat = np.ascontiguousarray(
-                    cells.transpose(1, 0, 2)).reshape(k, b * su)
-                par = native.rs_encode(codec.matrix, flat,
-                                       threads=os.cpu_count() or 1)
-                parity = np.ascontiguousarray(
-                    par.reshape(codec.m, b, su).transpose(1, 0, 2))
-                return parity, None
-            # cellwise codecs (bitmatrix, CLAY): the plugin's own
-            # vectorized host batch; CRCs stay the caller's separate
-            # multithreaded pass, like every host engine
-            host = getattr(codec, "encode_cells_host", None)
-            if host is not None:
-                return host(cells), None
-            return np.stack([codec.encode_chunks(c) for c in cells]), \
-                None
+            with trace.host_span("ec.host") as h:
+                out = self._host_encode(codec, cells)
+            clock.add(h.ns)
+            return out
         mesh = self.mesh()
         if mesh is not None and hasattr(codec, "encode_crc_batch_mesh"):
             return self._mesh_encode_sync(codec, cells, mesh)
+        import jax
+
         from ..ops import rs
 
-        batch = ECBatcher._pow2_pad(rs.pack_u32(cells))
-        parity_w, crcs = codec.encode_crc_batch(batch, su)
-        return (rs.unpack_u32(np.asarray(parity_w)[:b]),
-                np.asarray(crcs)[:b])
+        return self._device_stages(
+            clock,
+            lambda: codec.encode_crc_batch(
+                ECBatcher._pow2_pad(rs.pack_u32(cells)), su),
+            jax.block_until_ready,
+            lambda out: (np.asarray(out[0]), np.asarray(out[1])),
+            lambda host: (rs.unpack_u32(host[0][:b]), host[1][:b]))
+
+    @staticmethod
+    def _host_encode(codec, cells: np.ndarray):
+        """The host engine's encode: (parity, None)."""
+        b, k, su = cells.shape
+        if getattr(codec, "bytewise_linear", False):
+            # GF(2^8) matrix codes: ONE multithreaded C++ matmul
+            # over the shard-major flatten (reads the RMW staging
+            # buffer's contiguous storage back without a copy)
+            flat = np.ascontiguousarray(
+                cells.transpose(1, 0, 2)).reshape(k, b * su)
+            par = native.rs_encode(codec.matrix, flat,
+                                   threads=os.cpu_count() or 1)
+            parity = np.ascontiguousarray(
+                par.reshape(codec.m, b, su).transpose(1, 0, 2))
+            return parity, None
+        # cellwise codecs (bitmatrix, CLAY): the plugin's own
+        # vectorized host batch; CRCs stay the caller's separate
+        # multithreaded pass, like every host engine
+        host = getattr(codec, "encode_cells_host", None)
+        if host is not None:
+            return host(cells), None
+        return np.stack([codec.encode_chunks(c) for c in cells]), None
 
     def _mesh_encode_sync(self, codec, cells: np.ndarray, mesh):
         """Device-resident shard staging: ONE pad (pow2 + stripe-
@@ -590,14 +705,20 @@ class ECBatcher:
         from ..parallel import runtime
 
         b, k, su = cells.shape
-        batch = ECBatcher._pow2_pad(rs.pack_u32(cells), mesh)
-        parity_w, crcs_d = codec.encode_crc_batch_mesh(batch, su, mesh)
-        parity = runtime.shard_rows_to_host(parity_w)
-        crcs = runtime.shard_rows_to_host(crcs_d)
+        # the runtime stages, launches AND waits for the program under
+        # its dispatch lock, so ec.stage here is the pack and pad, and
+        # ec.device_wait the whole locked mesh dispatch
+        out = self._device_stages(
+            _stage_clock(),
+            lambda: ECBatcher._pow2_pad(rs.pack_u32(cells), mesh),
+            lambda batch: codec.encode_crc_batch_mesh(batch, su, mesh),
+            lambda out: (runtime.shard_rows_to_host(out[0]),
+                         runtime.shard_rows_to_host(out[1])),
+            lambda host: (rs.unpack_u32(host[0][:b]), host[1][:b]))
         runtime.STATS.bump(encode_stripes=b)
         if self.perf is not None:
             self.perf.inc("ec_mesh_encode_dispatches")
-        return rs.unpack_u32(parity[:b]), crcs[:b]
+        return out
 
     def _overdecomposed(self, cells: np.ndarray, run):
         """Rateless recovery over-decomposition (arXiv:1804.10331) —
@@ -715,14 +836,25 @@ class ECBatcher:
                              name="ec-shape-warm").start()
         return True
 
+    @staticmethod
+    def _timed_host(clock: _StageClock, run):
+        """A host-engine block dispatcher timed as one ``ec.host``
+        stage."""
+        def _dispatch_block(blk: np.ndarray) -> np.ndarray:
+            with trace.host_span("ec.host") as h:
+                out = run(blk)
+            clock.add(h.ns)
+            return out
+        return _dispatch_block
+
     def _host_decode_block(self, codec, present: tuple, want: tuple,
-                           kp: int, su: int):
+                           kp: int, su: int, clock: _StageClock):
         """Host-engine row-block dispatcher for decode, or None when
         the codec has no host hook."""
         if getattr(codec, "bytewise_linear", False):
             mat = codec.decode_matrix_for(present, want)
 
-            def _dispatch_block(blk: np.ndarray) -> np.ndarray:
+            def _matmul(blk: np.ndarray) -> np.ndarray:
                 bb = len(blk)
                 flat = np.ascontiguousarray(
                     blk.transpose(1, 0, 2)).reshape(kp, bb * su)
@@ -731,34 +863,32 @@ class ECBatcher:
                 return np.ascontiguousarray(
                     out.reshape(len(want), bb, su)
                     .transpose(1, 0, 2))
-            return _dispatch_block
+            return self._timed_host(clock, _matmul)
         host = getattr(codec, "decode_cells_host", None)
         if host is None:
             return None
+        return self._timed_host(clock,
+                                lambda blk: host(present, want, blk))
 
-        def _dispatch_block(blk: np.ndarray) -> np.ndarray:
-            return host(present, want, blk)
-        return _dispatch_block
-
-    def _host_repair_block(self, codec, present: tuple, want: tuple):
+    def _host_repair_block(self, codec, present: tuple, want: tuple,
+                           clock: _StageClock):
         """Host-engine row-block dispatcher for sub-chunk repair, or
         None when the codec has no host hook."""
         host = getattr(codec, "repair_cells_host", None)
         if host is None:
             return None
-
-        def _dispatch_block(blk: np.ndarray) -> np.ndarray:
-            return host(present, want, blk)
-        return _dispatch_block
+        return self._timed_host(clock,
+                                lambda blk: host(present, want, blk))
 
     def _decode_sync(self, codec, present: tuple, want: tuple,
                      cells: np.ndarray) -> np.ndarray:
         """(B, k', su) u8 survivors -> (B, len(want), su) u8."""
         engine = getattr(codec, "resolved_backend", lambda: "device")()
         b, kp, su = cells.shape
+        clock = _stage_clock()
         if engine == "host" or not hasattr(codec, "decode_batch"):
             _dispatch_block = self._host_decode_block(codec, present,
-                                                      want, kp, su)
+                                                      want, kp, su, clock)
             if _dispatch_block is None:
                 raise RuntimeError(
                     f"codec {type(codec).__name__} has no batched "
@@ -775,13 +905,20 @@ class ECBatcher:
                 # the cold-shape shield: mesh rounds are storm-sized)
                 return self._mesh_decode_sync(codec, present, want,
                                               cells, mesh, mode)
+            import jax
+
             from ..ops import rs
 
             def _dispatch_block(blk: np.ndarray) -> np.ndarray:
                 bb = len(blk)
-                batch = ECBatcher._pow2_pad(rs.pack_u32(blk))
-                out = codec.decode_batch(present, batch, want=want)
-                return rs.unpack_u32(np.asarray(out)[:bb])
+                return self._device_stages(
+                    clock,
+                    lambda: codec.decode_batch(
+                        present, ECBatcher._pow2_pad(rs.pack_u32(blk)),
+                        want=want),
+                    jax.block_until_ready,
+                    lambda out: np.asarray(out),
+                    lambda host: rs.unpack_u32(host[:bb]))
             if ((getattr(codec, "bytewise_linear", False)
                     or getattr(codec, "decode_cells_host", None)
                     is not None)
@@ -790,7 +927,7 @@ class ECBatcher:
                          present, want), cells.nbytes,
                         lambda blk=cells: _dispatch_block(blk))):
                 shield = self._host_decode_block(codec, present, want,
-                                                 kp, su)
+                                                 kp, su, clock)
                 if self.perf is not None:
                     self.perf.inc("ec_decode_cold_host")
                 out = self._overdecomposed(cells, shield)
@@ -805,27 +942,36 @@ class ECBatcher:
         cells — the regenerating-code sub-chunk repair dispatch
         (padded zero stripes repair to zero cells: all-linear)."""
         engine = getattr(codec, "resolved_backend", lambda: "device")()
+        clock = _stage_clock()
         if engine == "host" or not hasattr(codec, "repair_batch"):
             _dispatch_block = self._host_repair_block(codec, present,
-                                                      want)
+                                                      want, clock)
             if _dispatch_block is None:
                 raise RuntimeError(
                     f"codec {type(codec).__name__} has no batched "
                     "sub-chunk repair")
         else:
+            import jax
+
             from ..ops import rs
 
             def _dispatch_block(blk: np.ndarray) -> np.ndarray:
                 bb = len(blk)
-                batch = ECBatcher._pow2_pad(rs.pack_u32(blk))
-                out = codec.repair_batch(present, batch, want)
-                return rs.unpack_u32(np.asarray(out)[:bb])
+                return self._device_stages(
+                    clock,
+                    lambda: codec.repair_batch(
+                        present, ECBatcher._pow2_pad(rs.pack_u32(blk)),
+                        want),
+                    jax.block_until_ready,
+                    lambda out: np.asarray(out),
+                    lambda host: rs.unpack_u32(host[:bb]))
             if (getattr(codec, "repair_cells_host", None) is not None
                     and self._cold_shape(
                         ("rep", codec_profile_key(codec),
                          cells.shape[-1], present, want), cells.nbytes,
                         lambda blk=cells: _dispatch_block(blk))):
-                shield = self._host_repair_block(codec, present, want)
+                shield = self._host_repair_block(codec, present, want,
+                                                 clock)
                 if self.perf is not None:
                     self.perf.inc("ec_decode_cold_host")
                 out = self._overdecomposed(cells, shield)
@@ -845,10 +991,15 @@ class ECBatcher:
         from ..parallel import runtime
 
         b, kp, su = cells.shape
-        batch = ECBatcher._pow2_pad(rs.pack_u32(cells), mesh)
-        out = codec.decode_batch_mesh(present, batch, want, mesh, method)
-        host = runtime.shard_rows_to_host(out)
+        # as _mesh_encode_sync: the locked mesh dispatch is the wait
+        out = self._device_stages(
+            _stage_clock(),
+            lambda: ECBatcher._pow2_pad(rs.pack_u32(cells), mesh),
+            lambda batch: codec.decode_batch_mesh(present, batch, want,
+                                                  mesh, method),
+            runtime.shard_rows_to_host,
+            lambda host: rs.unpack_u32(host[:b]))
         runtime.STATS.bump(decode_stripes=b)
         if self.perf is not None:
             self.perf.inc("ec_mesh_decode_dispatches")
-        return rs.unpack_u32(host[:b])
+        return out

@@ -18,6 +18,7 @@ from different client ops can meet in the same batch.
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import os
 import sys
 import time
@@ -34,8 +35,8 @@ from ..utils import trace
 from ..utils.fault import FaultInjector
 from ..utils.perf import PerfCounters
 from . import messages as M
+from . import optracker
 from .ecbatch import ECBatcher  # noqa: F401  (re-export: the public seam)
-from .optracker import OpTracker
 from .pg import NONE, PG
 from .scheduler import CLIENT, RECOVERY, SCRUB, MClockScheduler, Throttle
 
@@ -127,7 +128,7 @@ class OSDLite:
                 <= self.ec_batcher.parked() + self.op_lock_waiters),
             fault=self.fault)
         self.throttle = Throttle(self.conf["osd_client_message_size_cap"])
-        self.optracker = OpTracker()
+        self.optracker = optracker.OpTracker()
         self.tracer = trace.get_tracer(self.name)
         self.pending: dict = {}  # key -> Future (sub-op replies)
         # sub-op tids carry an incarnation nonce in the high bits (the
@@ -166,6 +167,19 @@ class OSDLite:
         p.add_u64_counter("op_r", "client reads")
         p.add_u64_counter("op_w", "client writes")
         p.add_time_avg("op_latency", "client op latency")
+        # the client op in stages (cluster/optracker.py stage timers):
+        # each boundary's one clock read is also the op's timeline mark
+        p.add_time_avg("op_queue_lat",
+                       "client op arrival (before the byte throttle) "
+                       "to dequeue by an op worker")
+        p.add_time_avg("op_pg_lock_lat",
+                       "client op wait for the PG lock")
+        p.add_time_avg("op_ec_lat",
+                       "client op wait on the ECBatcher (encode, "
+                       "decode, repair)")
+        p.add_time_avg("op_subop_lat",
+                       "client op sub-op fan-out: first send to the "
+                       "last reply it waits for, once per fan-out")
         p.add_u64_counter("subop_w", "replica/shard sub-writes applied")
         ECBatcher.declare_counters(p)
         p.add_u64_counter("recovery_pushes", "objects pushed to peers")
@@ -241,7 +255,11 @@ class OSDLite:
     # ----------------------------------------------------------- plumbing
 
     def spawn(self, coro) -> asyncio.Task:
-        task = asyncio.get_running_loop().create_task(coro)
+        """Background task, detached from the spawning client op: it
+        must not time its stages into that op (optracker.stage)."""
+        ctx = contextvars.copy_context()
+        ctx.run(optracker.current.set, None)
+        task = asyncio.get_running_loop().create_task(coro, context=ctx)
         self._tasks.add(task)
         task.add_done_callback(self._tasks.discard)
         return task
@@ -660,10 +678,15 @@ class OSDLite:
     async def handle(self, src: str, msg) -> None:
         if self.stopped:
             return
+        # a delivery task inherits the context of whatever task sent
+        # the message — never time its work into the sender's op
+        token = optracker.current.set(None)
         try:
             await self._handle(src, msg)
         except Exception:
             self.log_exc(f"dispatch {type(msg).__name__} from {src}")
+        finally:
+            optracker.current.reset(token)
 
     async def _handle(self, src: str, msg) -> None:
         if isinstance(msg, M.MOSDMapMsg):
@@ -671,11 +694,15 @@ class OSDLite:
         elif isinstance(msg, M.MOSDOp):
             # enqueue_op role: client ops take the mClock queue under
             # the ingest byte throttle; sub-ops and control traffic stay
-            # fast-dispatch
+            # fast-dispatch. The op's "queued" stamp is its arrival, so
+            # the throttle wait counts toward op_queue_lat; it joins
+            # the in-flight set (which the batcher's idle probe counts)
+            # only once admitted
+            arrived = time.time_ns()
             await self.throttle.acquire(_op_bytes(msg))
             tracked = self.optracker.create(
                 f"osd_op tid={msg.tid} {msg.oid!r} "
-                f"[{','.join(o[0] for o in msg.ops)}]"
+                f"[{','.join(o[0] for o in msg.ops)}]", start_ns=arrived
             )
             self.op_scheduler.enqueue(
                 self._qos_class(src),
@@ -784,7 +811,9 @@ class OSDLite:
     async def _client_op(self, src: str, msg: M.MOSDOp,
                          tracked=None) -> None:
         if tracked is not None:
-            tracked.mark("dequeued")
+            self.perf.tinc("op_queue_lat",
+                           (tracked.mark("dequeued") - tracked.start_ns)
+                           * 1e-9)
         # injected per-op stall (ms_inject_delay cousin). Deliberately
         # BEFORE any PG lock is taken: fault pauses under a PG lock
         # would stall the whole PG, which tpulint's lock-discipline
@@ -822,7 +851,11 @@ class OSDLite:
                 return
             if tracked is not None:
                 tracked.mark("reached_pg")
-            await pg.do_op(src, msg)
+            token = optracker.current.set(tracked)
+            try:
+                await pg.do_op(src, msg)
+            finally:
+                optracker.current.reset(token)
         finally:
             if tracked is not None:
                 self.optracker.finish(tracked)

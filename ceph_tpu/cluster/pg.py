@@ -43,6 +43,7 @@ from . import messages as M
 from . import snaps as sn
 from . import stripe as st
 from .hedge import hedged_fanout
+from .optracker import stage
 from .pglog import (OP_DELETE, OP_MODIFY, ZERO, Entry, PGInfo, PGLog,
                     dec_missing, enc_missing)
 
@@ -804,8 +805,7 @@ class PG:
         verb = m.ops[0][0] if m.ops else "noop"
         span = self.osd.tracer.start_span(
             f"pg.do_op {verb}", parent=m.trace
-        ).tag("pgid", self.pgid).tag("oid",
-                                     m.oid[:64].decode(errors="replace"))
+        ).tag("pgid", self.pgid).tag("oid", m.oid)
         ctx_token = tr.current.set(span.ctx)
         try:
             await self._do_op_traced(src, m, perf)
@@ -864,7 +864,8 @@ class PG:
                 # batcher must not hold a batch open waiting for it.
                 self.osd.op_lock_waiters += 1
                 try:
-                    await self.lock.acquire()
+                    with stage(perf, "op_pg_lock_lat", "pg_locked"):
+                        await self.lock.acquire()
                 finally:
                     self.osd.op_lock_waiters -= 1
                 try:
@@ -1324,44 +1325,45 @@ class PG:
         # are classified per target: an acting send failure fails the
         # op through the SAME cleanup path as a failed ack (demote +
         # re-peer, pending futures dropped); extras stay best-effort.
-        n_act = len(peers)
-        shipped = await asyncio.gather(
-            *(_ship(o) for o, _s in peers),
-            *(_ship(o) for o, _s in extra_peers),
-            return_exceptions=True)
-        waits, extra_waits = [], []
-        extras_ok, acting_exc = True, None
-        for i, res in enumerate(shipped):
-            if isinstance(res, BaseException):
-                if i < n_act:
-                    acting_exc = acting_exc or res
+        with stage(self.osd.perf, "op_subop_lat", "sub_ops_done"):
+            n_act = len(peers)
+            shipped = await asyncio.gather(
+                *(_ship(o) for o, _s in peers),
+                *(_ship(o) for o, _s in extra_peers),
+                return_exceptions=True)
+            waits, extra_waits = [], []
+            extras_ok, acting_exc = True, None
+            for i, res in enumerate(shipped):
+                if isinstance(res, BaseException):
+                    if i < n_act:
+                        acting_exc = acting_exc or res
+                    else:
+                        extras_ok = False
+                elif i < n_act:
+                    waits.append(res)
                 else:
-                    extras_ok = False
-            elif i < n_act:
-                waits.append(res)
-            else:
-                extra_waits.append(res)
-        try:
-            if acting_exc is not None:
-                raise acting_exc
-            await self.osd.gather(waits)
-            # primary's own apply joins the all-acked barrier (group-
-            # commit stores defer the flush past queue_transaction)
-            await self.osd.txn_durable(local_barrier)
-        except BaseException:
-            for _o, subtid, _f in waits + extra_waits:
-                self.osd.drop_reply(subtid)
-            self._mig_fanout_done(entries[-1].oid, ok=False)
-            self._repeer_on_subop_failure()
-            raise
-        # ACTING all-acked: the op succeeds and the fence head advances
-        # regardless of the extras — migration targets are best-effort
-        # (the reference's backfill targets never fail client IO); a
-        # bounced/lost extra delta just demotes the oid for re-push
-        if entries[-1].version > self.acked_head:
-            self.acked_head = entries[-1].version
-        await self._gather_extras(entries[-1].oid, extra_waits,
-                                  ok=extras_ok)
+                    extra_waits.append(res)
+            try:
+                if acting_exc is not None:
+                    raise acting_exc
+                await self.osd.gather(waits)
+                # primary's own apply joins the all-acked barrier (group-
+                # commit stores defer the flush past queue_transaction)
+                await self.osd.txn_durable(local_barrier)
+            except BaseException:
+                for _o, subtid, _f in waits + extra_waits:
+                    self.osd.drop_reply(subtid)
+                self._mig_fanout_done(entries[-1].oid, ok=False)
+                self._repeer_on_subop_failure()
+                raise
+            # ACTING all-acked: the op succeeds and the fence head advances
+            # regardless of the extras — migration targets are best-effort
+            # (the reference's backfill targets never fail client IO); a
+            # bounced/lost extra delta just demotes the oid for re-push
+            if entries[-1].version > self.acked_head:
+                self.acked_head = entries[-1].version
+            await self._gather_extras(entries[-1].oid, extra_waits,
+                                      ok=extras_ok)
 
     async def _gather_extras(self, oid: bytes, extra_waits,
                              ok: bool = True) -> None:
@@ -1508,8 +1510,9 @@ class PG:
             osd.perf.inc("ov_apply_calls")
             osd.perf.inc("ov_apply_extents", n_ext)
             osd.perf.inc("ov_apply_stripes", n_cols)
-            parity, fused = await osd.ec_batcher.encode_cells(
-                codec, data_sh.transpose(1, 0, 2))
+            with stage(osd.perf, "op_ec_lat", "ec_done"):
+                parity, fused = await osd.ec_batcher.encode_cells(
+                    codec, data_sh.transpose(1, 0, 2))
             par_sh[:] = parity.transpose(1, 0, 2)
             if fused is not None:
                 # device engine: the per-cell hash_info CRCs came back
@@ -1664,44 +1667,47 @@ class PG:
                                   prev_head=self.acked_head,
                                   trace=_trace_ctx()),
                 )))
-        extras_ok, acting_exc = True, None
-        if sends:
-            # one concurrent burst, not k+m serialized awaits: a corked
-            # wire messenger turns the whole fan-out into one write +
-            # one drain per peer connection. Failures classify per
-            # target: acting sends fail the op via the cleanup path
-            # below; extra (migration) sends stay best-effort — but a
-            # failed extra's wait is dropped NOW, or _gather_extras
-            # would stall a whole subop_timeout on a reply that can
-            # never come
-            results = await asyncio.gather(*(s for *_x, s in sends),
-                                           return_exceptions=True)
-            for (is_extra, wait, _s), res in zip(sends, results):
-                if isinstance(res, BaseException):
-                    if is_extra:
-                        extras_ok = False
-                        extra_waits.remove(wait)
-                        osd.drop_reply(wait[1])
-                    elif acting_exc is None:
-                        acting_exc = res
-        try:
-            if acting_exc is not None:
-                raise acting_exc
-            await osd.gather(waits)
-            # the primary's OWN shard must be as durable as the acks it
-            # just gathered before the client sees success
-            for barrier in local_barriers:
-                await osd.txn_durable(barrier)
-        except BaseException:
-            for _t, subtid, _f in waits + extra_waits:
-                osd.drop_reply(subtid)
-            self._mig_fanout_done(oid, ok=False)
-            self._repeer_on_subop_failure()
-            raise
-        # see _rep_fanout: acting all-acked; extras are best-effort
-        if version > self.acked_head:
-            self.acked_head = version
-        await self._gather_extras(oid, extra_waits, ok=extras_ok)
+        # first send to the last reply: timed once per fan-out, never
+        # per sub-op (the sub-op waits overlap)
+        with stage(osd.perf, "op_subop_lat", "sub_ops_done"):
+            extras_ok, acting_exc = True, None
+            if sends:
+                # one concurrent burst, not k+m serialized awaits: a corked
+                # wire messenger turns the whole fan-out into one write +
+                # one drain per peer connection. Failures classify per
+                # target: acting sends fail the op via the cleanup path
+                # below; extra (migration) sends stay best-effort — but a
+                # failed extra's wait is dropped NOW, or _gather_extras
+                # would stall a whole subop_timeout on a reply that can
+                # never come
+                results = await asyncio.gather(*(s for *_x, s in sends),
+                                               return_exceptions=True)
+                for (is_extra, wait, _s), res in zip(sends, results):
+                    if isinstance(res, BaseException):
+                        if is_extra:
+                            extras_ok = False
+                            extra_waits.remove(wait)
+                            osd.drop_reply(wait[1])
+                        elif acting_exc is None:
+                            acting_exc = res
+            try:
+                if acting_exc is not None:
+                    raise acting_exc
+                await osd.gather(waits)
+                # the primary's OWN shard must be as durable as the acks it
+                # just gathered before the client sees success
+                for barrier in local_barriers:
+                    await osd.txn_durable(barrier)
+            except BaseException:
+                for _t, subtid, _f in waits + extra_waits:
+                    osd.drop_reply(subtid)
+                self._mig_fanout_done(oid, ok=False)
+                self._repeer_on_subop_failure()
+                raise
+            # see _rep_fanout: acting all-acked; extras are best-effort
+            if version > self.acked_head:
+                self.acked_head = version
+            await self._gather_extras(oid, extra_waits, ok=extras_ok)
 
     def _repeer_on_subop_failure(self) -> None:
         """An acting member failed/bounced a sub-write: something is
@@ -1807,18 +1813,19 @@ class PG:
                              oid=oid, offset=0, length=0,
                              trace=_trace_ctx()),
             ))
-        if sends:
-            try:
-                await asyncio.gather(*sends)
-            except BaseException:
-                for _t, subtid, _f in waits:
-                    self.osd.drop_reply(subtid)
-                raise
-        found = None
-        for target, subtid, fut in waits:
-            reply = await self.osd.await_reply(subtid, fut, target)
-            if reply.result == M.OK and found is None:
-                found = (reply.size, reply.attrs)
+        with stage(self.osd.perf, "op_subop_lat", "sub_ops_done"):
+            if sends:
+                try:
+                    await asyncio.gather(*sends)
+                except BaseException:
+                    for _t, subtid, _f in waits:
+                        self.osd.drop_reply(subtid)
+                    raise
+            found = None
+            for target, subtid, fut in waits:
+                reply = await self.osd.await_reply(subtid, fut, target)
+                if reply.result == M.OK and found is None:
+                    found = (reply.size, reply.attrs)
         return found
 
     def _hedge_extra(self) -> int:
@@ -2059,8 +2066,9 @@ class PG:
 
                 out = {}
                 if primary:
-                    out = await hedged_fanout(osd, primary, extras,
-                                              _suff, nbytes=_nbytes)
+                    with stage(osd.perf, "op_subop_lat", "sub_ops_done"):
+                        out = await hedged_fanout(osd, primary, extras,
+                                                  _suff, nbytes=_nbytes)
                 exc = None
                 for j in sorted(out):
                     r = out[j]
@@ -2214,8 +2222,9 @@ class PG:
                 surv[row, : c.size] = c
             surv = np.ascontiguousarray(
                 surv.reshape(len(order), ncells, si.su).transpose(1, 0, 2))
-            return await self.osd.ec_batcher.decode_cells(
-                codec, present, want_generators, surv)
+            with stage(self.osd.perf, "op_ec_lat", "ec_done"):
+                return await self.osd.ec_batcher.decode_cells(
+                    codec, present, want_generators, surv)
         # chunk-codeword codecs without a batched API: one scalar
         # codec.decode over whole (padded) chunks
         arrs = {
@@ -3407,8 +3416,9 @@ class PG:
         present_g = tuple(codec._position_to_generator(p)
                           for p in order)
         want_g = (codec._position_to_generator(shard),)
-        rebuilt = await self.osd.ec_batcher.repair_cells(
-            codec, present_g, want_g, surv)
+        with stage(self.osd.perf, "op_ec_lat", "ec_done"):
+            rebuilt = await self.osd.ec_batcher.repair_cells(
+                codec, present_g, want_g, surv)
         chunk_arr = np.ascontiguousarray(
             rebuilt[:, 0, :]).reshape(-1)
         self.osd.perf.inc("ec_repair_subchunk")

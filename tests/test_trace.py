@@ -5,6 +5,9 @@ standalone exporter's admin-socket scrape."""
 import asyncio
 import importlib.util
 import os
+import sys
+import threading
+import time
 
 from ceph_tpu.cluster import TestCluster
 from ceph_tpu.placement.osdmap import Pool
@@ -115,3 +118,127 @@ def test_admin_socket_dump_tracing_and_exporter(tmp_path):
         await c.stop()
 
     run(t())
+
+
+def test_span_ids_unique_across_threads_and_ns_stamps():
+    """Ids need no lock: spans started on several threads at once never
+    collide. Stamps are integer time.time_ns()."""
+    t = trace.get_tracer("svc-ids")
+    ids: list[int] = []
+    n_threads = 2 * (os.cpu_count() or 1) + 2
+
+    def work():
+        spans = [t.start_span("x") for _ in range(1000)]
+        for sp in spans:
+            sp.finish()
+        ids.extend(sp.span_id for sp in spans)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work) for _ in range(n_threads)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+            assert not th.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    assert len(ids) == 1000 * n_threads
+    assert len(set(ids)) == len(ids)
+    before = time.time_ns()
+    sp = t.start_span("y")
+    sp.finish()
+    assert isinstance(sp.start_ns, int) and isinstance(sp.duration_ns, int)
+    assert before <= sp.start_ns <= time.time_ns()
+    assert sp.duration_ns >= 0
+
+
+def test_dump_keeps_zipkin_shape_and_formats_raw_tags():
+    t = trace.get_tracer("svc-zipkin")
+    with t.start_span("op") as sp:
+        sp.tag("oid", b"obj\xff").tag("pgid", (2, 3)).tag("result", 0)
+    d = t.dump(trace_id=sp.trace_id)[-1]
+    assert set(d) == {"traceId", "id", "parentId", "localEndpoint",
+                      "name", "timestamp", "duration", "tags"}
+    assert d["traceId"] == f"{sp.trace_id:016x}"
+    assert d["id"] == f"{sp.span_id:016x}" and d["parentId"] is None
+    assert d["localEndpoint"] == {"serviceName": "svc-zipkin"}
+    assert d["timestamp"] == sp.start_ns // 1000  # zipkin micros
+    assert d["duration"] == sp.duration_ns // 1000
+    assert d["tags"] == {"oid": "obj\ufffd", "pgid": "(2, 3)",
+                         "result": "0"}
+
+
+def test_host_span_times_its_block():
+    with trace.host_span("ec.test") as hs:
+        time.sleep(0.002)
+    assert isinstance(hs.ns, int) and hs.ns >= 2_000_000
+
+
+def _stage_sums(osds) -> dict:
+    keys = ("op_latency", "op_queue_lat", "op_pg_lock_lat", "op_ec_lat",
+            "op_subop_lat")
+    out = {k: [0.0, 0] for k in keys}
+    for osd in osds:
+        if osd is None:  # killed
+            continue
+        d = osd.perf.dump()
+        for k in keys:
+            out[k][0] += d[k]["sum"]
+            out[k][1] += d[k]["avgcount"]
+    return out
+
+
+def test_ec_op_stages_count_and_mark_in_order():
+    """An EC write and a degraded EC read bump the OSD op-stage
+    counters; the PG's stages never sum past the op latency they sit
+    in (a fan-out timed per sub-op would); and the historic op holds
+    the stage marks in the order the overwrite ran them."""
+    data = bytes(range(256)) * 96  # two stripes at k=3, su=4096
+
+    async def t():
+        c = TestCluster(n_osds=5)
+        await c.start()
+        await c.client.create_pool(
+            Pool(id=2, name="ec", size=5, min_size=3, pg_num=4,
+                 crush_rule=1, type="erasure",
+                 ec_profile={"plugin": "rs_tpu", "k": "3", "m": "2"}))
+        await c.wait_active(20)
+        # the first write of a name also probes the peers for the
+        # object's metadata (a fan-out of its own) before it encodes;
+        # the overwrite runs lock, encode, fan-out
+        await c.client.write_full(2, b"staged", data[::-1])
+        await c.client.write_full(2, b"staged", data)
+        w = _stage_sums(c.osds)
+        assert w["op_queue_lat"][1] >= 1
+        for k in ("op_pg_lock_lat", "op_ec_lat", "op_subop_lat"):
+            assert w[k][1] >= 1 and w[k][0] > 0, k
+        osdmap = c.mon.osdmap
+        acting = list(osdmap.pg_to_up_acting_osds(
+            osdmap.object_to_pg(2, b"staged"))[0])
+        ops = c.osds[acting[0]].optracker.dump_historic_ops()["ops"]
+        op = [o for o in ops if "staged" in o["description"]][-1]
+        events = [e["event"] for e in op["events"]]
+        order = ["queued", "dequeued", "reached_pg", "pg_locked",
+                 "ec_done", "sub_ops_done", "done"]
+        assert [e for e in events if e in order] == order, events
+        stamps = [e["time"] for e in op["events"]]
+        assert stamps == sorted(stamps)
+        # degraded read: data shard 1's OSD down, the read decodes
+        victim = acting[1]
+        await c.kill_osd(victim)
+        await c.wait_down(victim)
+        await c.wait_active(20)
+        assert await c.client.read(2, b"staged") == data
+        r = _stage_sums(c.osds)
+        await c.stop()
+        return r
+
+    r = asyncio.run(asyncio.wait_for(t(), 120))
+    for k in ("op_queue_lat", "op_pg_lock_lat", "op_ec_lat",
+              "op_subop_lat"):
+        assert r[k][1] >= 1, k
+    stages = sum(r[k][0] for k in ("op_pg_lock_lat", "op_ec_lat",
+                                   "op_subop_lat"))
+    assert 0 < stages <= r["op_latency"][0]
