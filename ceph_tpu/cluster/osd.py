@@ -180,6 +180,13 @@ class OSDLite:
         p.add_time_avg("op_subop_lat",
                        "client op sub-op fan-out: first send to the "
                        "last reply it waits for, once per fan-out")
+        p.add_u64_counter("ec_meta_probe",
+                          "EC metadata probe fan-outs sent (the "
+                          "primary's own shard could not decide an "
+                          "object's existence)")
+        p.add_u64_counter("ec_meta_local",
+                          "EC object absences decided on the primary's "
+                          "own shard, no probe sent")
         p.add_u64_counter("subop_w", "replica/shard sub-writes applied")
         ECBatcher.declare_counters(p)
         p.add_u64_counter("recovery_pushes", "objects pushed to peers")

@@ -272,7 +272,11 @@ class _OpState:
                 self.exists0 = True
                 self.size0 = denc.dec_u64(raw, 0)[0]
             except Exception:
-                meta = await pg._ec_remote_meta(oid)
+                if pg._absent_on_own_shard(oid):
+                    pg.osd.perf.inc("ec_meta_local")
+                    meta = None
+                else:
+                    meta = await pg._ec_remote_meta(oid)
                 if meta is not None:
                     self.exists0 = True
                     self.size0, attrs = meta
@@ -1794,11 +1798,28 @@ class PG:
         # awaited before ANY ack built on this write leaves the daemon
         return osd.queue_txn(full)
 
+    def _absent_on_own_shard(self, oid: bytes) -> bool:
+        """True when the primary's own shard, lacking ``oid``'s size
+        attr, is authoritative that the object does not exist (the
+        get_object_context role: a local ENOENT decides unless the
+        object is missing). An active primary recovered its own shard
+        before going active, and whatever it could not rebuild is on
+        record in ``missing``; a shard file without the size attr is a
+        torn or partial write, not an absence."""
+        return (self.is_primary() and self.state == "active"
+                and oid not in self.missing
+                and not self.osd.store.exists(self.cid, oid))
+
     async def _ec_remote_meta(self, oid: bytes):
         """(size, user-attrs) of an EC object from any peer shard, or
         None when absent everywhere (metadata-only sub-reads, length=0,
-        issued concurrently). Used when the primary's own shard lacks
-        the object (hole being backfilled)."""
+        issued concurrently). Runs only when the primary's own shard
+        lacks the size attr and cannot decide alone
+        (``_absent_on_own_shard`` false): the PG is not an active
+        primary, the object is on its ``missing`` record (a hole being
+        recovered), or a shard file exists without the attr (a torn
+        write)."""
+        self.osd.perf.inc("ec_meta_probe")
         waits = []
         sends = []
         for pos, target in sorted(

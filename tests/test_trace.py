@@ -205,9 +205,8 @@ def test_ec_op_stages_count_and_mark_in_order():
                  crush_rule=1, type="erasure",
                  ec_profile={"plugin": "rs_tpu", "k": "3", "m": "2"}))
         await c.wait_active(20)
-        # the first write of a name also probes the peers for the
-        # object's metadata (a fan-out of its own) before it encodes;
-        # the overwrite runs lock, encode, fan-out
+        # the first write of a name decides its absence on the
+        # primary's own shard; the overwrite runs lock, encode, fan-out
         await c.client.write_full(2, b"staged", data[::-1])
         await c.client.write_full(2, b"staged", data)
         w = _stage_sums(c.osds)
