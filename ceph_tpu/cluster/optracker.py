@@ -2,9 +2,9 @@
 OpRequest.h / OpTracker role).
 
 Every client op gets a TrackedOp carrying an event timeline
-(queued -> dequeued -> reached_pg -> pg_locked -> ec_done /
-sub_ops_done -> done, each with a timestamp); completed ops roll into
-a bounded history ring. The admin socket dumps both
+(queued -> dequeued -> reached_pg -> pg_locked -> rmw_read_done /
+ec_done / sub_ops_done -> done, each with a timestamp); completed ops
+roll into a bounded history ring. The admin socket dumps both
 (`dump_ops_in_flight` / `dump_historic_ops`), and slow ops (age > warn
 threshold) surface in health.
 

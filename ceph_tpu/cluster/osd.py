@@ -180,6 +180,18 @@ class OSDLite:
         p.add_time_avg("op_subop_lat",
                        "client op sub-op fan-out: first send to the "
                        "last reply it waits for, once per fan-out")
+        p.add_time_avg("op_rmw_read_lat",
+                       "client op EC read-modify-write: the old-stripe "
+                       "reads of a partial-stripe write (overlaps "
+                       "op_subop_lat)")
+        p.add_u64_counter("ec_rmw_read_bytes",
+                          "old-stripe bytes EC read-modify-writes read")
+        p.add_u64_counter("ec_user_bytes_written",
+                          "bytes EC writes were asked to write (their "
+                          "written ranges)")
+        p.add_u64_counter("ec_shard_bytes_written",
+                          "data and parity bytes EC writes put into "
+                          "shard transactions, over all k+m shards")
         p.add_u64_counter("ec_meta_probe",
                           "EC metadata probe fan-outs sent (the "
                           "primary's own shard could not decide an "

@@ -1455,7 +1455,9 @@ class PG:
         new_nst = si.nstripes(new_size)
 
         touched: set[int] = set()
+        user_bytes = 0
         for off, ln in ov.written_ranges():
+            user_bytes += ln
             s0, s1 = si.stripe_span(off, ln)
             touched.update(range(s0, min(s1, new_nst)))
         if new_size < old_size and new_size % si.width and new_nst:
@@ -1483,11 +1485,18 @@ class PG:
                 run_start, prev = s, s
         if run_start is not None:
             runs.append((run_start, prev + 1))
-        for a, b in runs:
-            start = a * si.width
-            end = min(b * si.width, old_size)
-            data, _sz = await self._read_ec(oid, start, end - start)
-            old_runs.append((a, data))
+        read_bytes = 0
+        if runs:
+            # the old-stripe read, a stage of its own (its sub-read
+            # fan-out is also inside op_subop_lat)
+            with stage(osd.perf, "op_rmw_read_lat", "rmw_read_done"):
+                for a, b in runs:
+                    start = a * si.width
+                    end = min(b * si.width, old_size)
+                    data, _sz = await self._read_ec(oid, start,
+                                                    end - start)
+                    old_runs.append((a, data))
+                    read_bytes += end - start
 
         tlist = sorted(touched)
         # Shard-major device STAGING buffer (the bufferlist seam of the
@@ -1537,6 +1546,7 @@ class PG:
             nz_d, nz_p = nz[:k], nz[k:]
         shard_txns: dict[int, tx.Transaction] = {}
         hpatches: dict[int, bytes] = {}
+        cells_written = 0
         for g in range(n):
             pos = codec.chunk_index(g)
             cid = self._shard_cid(pos)
@@ -1567,6 +1577,7 @@ class PG:
                         # contiguous staging view, not a tobytes copy
                         t.write(cid, oid, run_s * si.su,
                                 rows[run_i:i])
+                        cells_written += i - run_i
                         run_i = -1
                 if not skip:
                     if run_i < 0:
@@ -1575,6 +1586,7 @@ class PG:
             if run_i >= 0:
                 t.write(cid, oid, run_s * si.su,
                         rows[run_i:len(tlist)])
+                cells_written += len(tlist) - run_i
             for m_ in st8.xattr_muts:
                 if m_[0] == "set":
                     t.setattr(cid, oid, USER_ATTR + m_[1], m_[2])
@@ -1596,6 +1608,11 @@ class PG:
             # contract for untouched data anyway
             hpatches[pos] = (memoryview(patch).toreadonly().cast("B")
                              if patch.size else b"")
+        # the byte counters move together, so their ratios hold in
+        # any window
+        osd.perf.inc("ec_user_bytes_written", user_bytes)
+        osd.perf.inc("ec_shard_bytes_written", cells_written * si.su)
+        osd.perf.inc("ec_rmw_read_bytes", read_bytes)
         await self._ec_fanout(oid, entries, shard_txns, hpatch=hpatches,
                               ncells=new_nst, size=new_size, live=live,
                               extras=self._dual_write_extras(oid, st8))

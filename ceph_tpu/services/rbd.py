@@ -19,7 +19,12 @@ An image is a FileLayout-striped set of data objects
 (``rbd_data.<name>.<objectno:016x>``, default 4 MiB object size /
 stripe_count 1 — the rbd default layout) plus a header object
 (``rbd_header.<name>``) carrying size/layout/snap/parent metadata in
-xattrs (portable to EC data pools, where omap is unsupported).
+xattrs. The data objects may live in a pool of their own (``rbd create
+--data-pool``: librbd's data IoCtx), typically an erasure-coded pool,
+while header, object map, lock and every directory stay in the image's
+replicated pool (EC pools refuse omap). Snapshot ids come from the
+data pool, since the SnapContext a data write carries belongs to the
+pool that holds the data.
 
 Covered surface (librbd/Operations.cc + io/ dispatch roles):
 - create / remove / resize / stat / list
@@ -46,6 +51,7 @@ from ..osdc.striper import (
     file_to_extents,
 )
 from ..utils import denc
+from ..utils import trace
 
 
 class ImageNotFound(KeyError):
@@ -61,6 +67,7 @@ ATTR_LAYOUT = "rbd.layout"
 ATTR_SNAPS = "rbd.snaps"  # list of (name, RADOS selfmanaged snap id)
 ATTR_SNAPSEQ = "rbd.snapseq"  # image SnapContext seq (monotone)
 ATTR_PARENT = "rbd.parent"  # "name@snap" of the clone source
+ATTR_DATA_POOL = "rbd.data_pool"  # id of the pool holding data objects
 
 LOCK_NAME = "rbd_lock"  # the cls lock name (librbd RBD_LOCK_NAME)
 NOTIFY_REQUEST_LOCK = b"request_lock"
@@ -220,7 +227,10 @@ class RBD:
                                 [name.encode()])
 
     async def create(self, name: str, size: int,
-                     layout: FileLayout | None = None) -> None:
+                     layout: FileLayout | None = None,
+                     data_pool: int | None = None) -> None:
+        """New image; ``data_pool`` puts its data objects in that pool
+        (``rbd create --data-pool``), which must exist."""
         layout = layout or DEFAULT_LAYOUT
         from ..cluster.client import ObjectOperation
 
@@ -230,6 +240,11 @@ class RBD:
               .setxattr(ATTR_LAYOUT, _enc_layout(layout))
               .setxattr(ATTR_SNAPS, _enc_snaps([]))
               .setxattr(ATTR_SNAPSEQ, denc.enc_u64(0)))
+        if data_pool is not None and data_pool != self.pool_id:
+            osdmap = self._raw.osdmap
+            if osdmap is None or data_pool not in osdmap.pools:
+                raise KeyError(f"data pool {data_pool} does not exist")
+            op = op.setxattr(ATTR_DATA_POOL, denc.enc_u64(data_pool))
         if await self._trash_reserved(name):
             # a trashed image's data objects still carry this name —
             # a fresh image would silently share them (see trash note)
@@ -632,11 +647,13 @@ class RBD:
 
     async def clone(self, parent: str, snap: str, child: str) -> None:
         """Layered child image backed by parent@snap (librbd clone
-        role); unwritten extents read through to the parent."""
+        role); unwritten extents read through to the parent. The child
+        keeps its data in the parent's data pool."""
         p = await self.open(parent)
         if snap not in p.snaps:
             raise KeyError(f"{parent}@{snap}")
-        await self.create(child, p.size, p.layout)
+        await self.create(child, p.size, p.layout,
+                          data_pool=p.data_pool_id)
         await self.client.setxattr(
             self.pool_id, _header(child), ATTR_PARENT,
             f"{parent}@{snap}".encode(),
@@ -646,7 +663,8 @@ class RBD:
 
     async def deep_copy(self, src_name: str, dst_name: str,
                         dst_rbd: "RBD | None" = None,
-                        layout: FileLayout | None = None) -> None:
+                        layout: FileLayout | None = None,
+                        data_pool: int | None = None) -> None:
         """Full image copy INCLUDING snapshot history, optionally to
         another pool and/or a new layout (librbd DeepCopyRequest role,
         src/librbd/DeepCopyRequest.cc): each source snapshot level
@@ -659,7 +677,8 @@ class RBD:
             raise ImageExists(dst_name)
         except ImageNotFound:
             pass
-        await dst_rbd.create(dst_name, src.size, layout or src.layout)
+        await dst_rbd.create(dst_name, src.size, layout or src.layout,
+                             data_pool=data_pool)
         dst = await dst_rbd.open(dst_name)
         await dst.acquire_lock()
         try:
@@ -679,7 +698,7 @@ class RBD:
         await src0.refresh()
         async def probe(objno: int):
             try:
-                await self.client.stat(dst.pool_id, dst._oid(objno))
+                await self.client.stat(dst.data_pool_id, dst._oid(objno))
                 return objno
             except KeyError:
                 return None
@@ -709,7 +728,7 @@ class RBD:
                     continue  # unchanged at this level: snap shares it
                 await dst._omap_prewrite((objno,))
                 await self.client.write_full(
-                    dst.pool_id, dst._oid(objno), content,
+                    dst.data_pool_id, dst._oid(objno), content,
                     snapc=dst._snapc())
                 dst._omap_settle(objno, True)  # exists (maybe empty)
                 prev[objno] = content
@@ -718,8 +737,8 @@ class RBD:
 
     async def migration_prepare(self, src_name: str, dst_name: str,
                                 dst_rbd: "RBD | None" = None,
-                                layout: FileLayout | None = None
-                                ) -> None:
+                                layout: FileLayout | None = None,
+                                data_pool: int | None = None) -> None:
         """Link src -> dst for live migration (librbd migration role,
         src/librbd/api/Migration.cc): after prepare, clients open the
         TARGET (the source refuses opens); target reads fall through
@@ -733,7 +752,8 @@ class RBD:
             raise ImageExists(dst_name)
         except ImageNotFound:
             pass
-        await dst_rbd.create(dst_name, src.size, layout or src.layout)
+        await dst_rbd.create(dst_name, src.size, layout or src.layout,
+                             data_pool=data_pool)
         await dst_rbd.client.setxattr(
             dst_rbd.pool_id, _header(dst_name), ATTR_MIGRATION_SOURCE,
             f"{self.pool_id}/{src_name}".encode())
@@ -813,6 +833,12 @@ def _enc_layout(lo: FileLayout) -> bytes:
             + denc.enc_u64(lo.object_size))
 
 
+def _data_pool_of(attrs: dict, pool_id: int) -> int:
+    """The data pool a header names, else the image's own pool."""
+    raw = attrs.get(ATTR_DATA_POOL)
+    return denc.dec_u64(raw, 0)[0] if raw else pool_id
+
+
 def _dec_layout(b: bytes) -> FileLayout:
     su, off = denc.dec_u64(b, 0)
     sc, off = denc.dec_u64(b, off)
@@ -828,7 +854,11 @@ class Image:
                  cache: bool = False, allow_migrating: bool = False):
         self.client = client
         self.pool_id = pool_id
+        #: the pool of the data objects (the header's data-pool attr;
+        #: the image's own pool without one), known from refresh()
+        self.data_pool_id = pool_id
         self.name = name
+        self._tracer = trace.get_tracer(client.name)
         #: internal opens during migration bypass the mid-migration
         #: guard (clients must open the TARGET, librbd migration role)
         self._allow_migrating = allow_migrating
@@ -839,14 +869,11 @@ class Image:
         #: reads acquire it, librbd's exclusive-lock+cache behavior),
         #: flushed + invalidated at every ownership/snapshot boundary.
         #: _io is the data-path client: the CacheIo facade when caching,
-        #: the raw client otherwise — call sites never branch.
+        #: the raw client otherwise — call sites never branch. The
+        #: cache is built on the data pool, so by the first refresh().
         self._cacher = None
         self._io = client
-        if cache and snap is None:
-            from ..osdc.object_cacher import CacheIo, ObjectCacher
-
-            self._cacher = ObjectCacher(client, pool_id)
-            self._io = CacheIo(client, self._cacher)
+        self._want_cache = cache and snap is None
         self.snap = snap
         self.size = 0
         self.layout = DEFAULT_LAYOUT
@@ -855,6 +882,7 @@ class Image:
         self.snap_seq = 0
         self.parent: tuple[str, str] | None = None
         self._parent_snapid: int | None = None
+        self._parent_data_pool: int = pool_id
         #: exclusive-lock state (ExclusiveLock.h:20 role). The owner is
         #: the CLIENT entity (what the blocklist fences); the cookie
         #: distinguishes handles of one client.
@@ -1087,7 +1115,8 @@ class Image:
             # by a crashed/fenced holder (rebuild-object-map role)
             async def probe(i):
                 try:
-                    await self.client.stat(self.pool_id, self._oid(i))
+                    await self.client.stat(self.data_pool_id,
+                                           self._oid(i))
                     bits[i] = 1
                 except KeyError:
                     bits[i] = 0
@@ -1179,6 +1208,12 @@ class Image:
             self._mig_src = src
         elif not raw_src:
             self._mig_src = None
+        self.data_pool_id = _data_pool_of(attrs, self.pool_id)
+        if self._want_cache and self._cacher is None:
+            from ..osdc.object_cacher import CacheIo, ObjectCacher
+
+            self._cacher = ObjectCacher(self.client, self.data_pool_id)
+            self._io = CacheIo(self.client, self._cacher)
         self.size = denc.dec_u64(attrs[ATTR_SIZE], 0)[0]
         self.layout = _dec_layout(attrs[ATTR_LAYOUT])
         pairs = _dec_snaps(attrs[ATTR_SNAPS])
@@ -1202,6 +1237,7 @@ class Image:
                 raise ImageNotFound(
                     f"clone source {pname}@{psnap} is gone")
             self._parent_snapid = pids[psnap]
+            self._parent_data_pool = _data_pool_of(pattrs, self.pool_id)
         else:
             self.parent = None
             self._parent_snapid = None
@@ -1240,7 +1276,7 @@ class Image:
                 elif want < retained_bytes(lo, old, objno):
                     try:
                         await self.client.truncate(
-                            self.pool_id, self._oid(objno), want,
+                            self.data_pool_id, self._oid(objno), want,
                             snapc=self._snapc(),
                         )
                     except KeyError:
@@ -1274,6 +1310,12 @@ class Image:
         return _data_fmt(self.name).format(objectno=objectno).encode()
 
     async def write(self, offset: int, data: bytes) -> None:
+        with self._tracer.start_span("rbd.write") as span:
+            span.tag("image", self.name).tag("offset", offset) \
+                .tag("length", len(data))
+            await self._write(offset, data)
+
+    async def _write(self, offset: int, data: bytes) -> None:
         self._writable()
         if offset + len(data) > self.size:
             raise IOError(
@@ -1293,7 +1335,7 @@ class Image:
                     piece[pos : pos + ln] = data[bo : bo + ln]
                     pos += ln
                 await self._copy_up(ex.objectno)
-                await self._io.write(self.pool_id, ex.oid, ex.offset,
+                await self._io.write(self.data_pool_id, ex.oid, ex.offset,
                                      bytes(piece),
                                      snapc=self._snapc())
                 self._omap_settle(ex.objectno, True)
@@ -1313,7 +1355,7 @@ class Image:
             # died between marking and writing) — fall through to stat.
             return
         try:
-            await self.client.stat(self.pool_id, self._oid(objectno))
+            await self.client.stat(self.data_pool_id, self._oid(objectno))
             return  # child already owns this object
         except KeyError:
             pass
@@ -1322,7 +1364,8 @@ class Image:
             src = _data_fmt(pname).format(objectno=objectno).encode()
             try:
                 blob = await self.client.read(
-                    self.pool_id, src, snapid=self._parent_snapid)
+                    self._parent_data_pool, src,
+                    snapid=self._parent_snapid)
             except KeyError:
                 return  # parent hole: child object starts empty
         else:  # migration target: pull the object's bytes from the
@@ -1333,12 +1376,18 @@ class Image:
                 return  # source hole
         await self._omap_prewrite((objectno,))
         await self._io.write_full(
-            self.pool_id, self._oid(objectno), blob,
+            self.data_pool_id, self._oid(objectno), blob,
             snapc=self._snapc(),
         )
         self._omap_settle(objectno, True)
 
     async def read(self, offset: int, length: int) -> bytes:
+        with self._tracer.start_span("rbd.read") as span:
+            span.tag("image", self.name).tag("offset", offset) \
+                .tag("length", length)
+            return await self._read(offset, length)
+
+    async def _read(self, offset: int, length: int) -> bytes:
         length = max(0, min(length, self.size - offset))
         if length == 0:
             return b""
@@ -1362,7 +1411,7 @@ class Image:
             await self._ensure_lock()
         try:
             return await self._io.read(
-                self.pool_id, ex.oid, offset=ex.offset,
+                self.data_pool_id, ex.oid, offset=ex.offset,
                 length=ex.length, snapid=snapid,
             )
         except KeyError:
@@ -1375,7 +1424,7 @@ class Image:
             src = _data_fmt(pname).format(objectno=ex.objectno).encode()
             try:
                 return await self.client.read(
-                    self.pool_id, src, offset=ex.offset,
+                    self._parent_data_pool, src, offset=ex.offset,
                     length=ex.length, snapid=self._parent_snapid,
                 )
             except KeyError:
@@ -1412,7 +1461,7 @@ class Image:
                 await self._copy_up(ex.objectno)
                 try:
                     await self._io.zero(
-                        self.pool_id, ex.oid, ex.offset, ex.length,
+                        self.data_pool_id, ex.oid, ex.offset, ex.length,
                         snapc=self._snapc())
                 except KeyError:
                     pass  # never written: already zero
@@ -1424,7 +1473,7 @@ class Image:
 
     async def _rm_object(self, objno: int):
         try:
-            await self._io.delete(self.pool_id, self._oid(objno),
+            await self._io.delete(self.data_pool_id, self._oid(objno),
                                   snapc=self._snapc())
         except KeyError:
             pass
@@ -1463,7 +1512,7 @@ class Image:
             if snap in self.snaps:
                 raise ImageExists(f"{self.name}@{snap}")
             snapid = await self.client.selfmanaged_snap_create(
-                self.pool_id)
+                self.data_pool_id)
             self.snaps.append(snap)
             self.snap_ids[snap] = snapid
             self.snap_seq = max(self.snap_seq, snapid)
@@ -1478,7 +1527,8 @@ class Image:
             snapid = self.snap_ids.pop(snap)
             self.snaps.remove(snap)
             await self._save_snaps()
-        await self.client.selfmanaged_snap_remove(self.pool_id, snapid)
+        await self.client.selfmanaged_snap_remove(self.data_pool_id,
+                                                  snapid)
 
     async def snap_rollback(self, snap: str) -> None:
         self._writable()
@@ -1501,14 +1551,15 @@ class Image:
         async def rb(objno):
             try:
                 blob = await self.client.read(
-                    self.pool_id, self._oid(objno), snapid=snapid
+                    self.data_pool_id, self._oid(objno), snapid=snapid
                 )
             except KeyError:
                 await self._rm_object(objno)
                 return
             await self._omap_prewrite((objno,))
-            await self.client.write_full(self.pool_id, self._oid(objno),
-                                         blob, snapc=self._snapc())
+            await self.client.write_full(self.data_pool_id,
+                                         self._oid(objno), blob,
+                                         snapc=self._snapc())
             self._omap_settle(objno, True)
 
         await asyncio.gather(

@@ -183,3 +183,10 @@ def test_rbd_cli_lifecycle(tmp_path, capsys):
     assert rbd_cli.main(base + ["ls", "rbd"]) == 0
     outtxt = capsys.readouterr().out
     assert "child" not in outtxt and "vault" in outtxt
+    # --data-pool: the image's data objects in a pool of their own
+    assert rbd_cli.main(base + ["mkpool", "rbddata"]) == 0
+    assert rbd_cli.main(base + ["create", "rbd/split", "--size", "1M",
+                                "--data-pool", "rbddata"]) == 0
+    capsys.readouterr()
+    assert rbd_cli.main(base + ["info", "rbd/split"]) == 0
+    assert "data_pool: rbddata" in capsys.readouterr().out

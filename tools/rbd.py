@@ -5,6 +5,8 @@ durable BlueStoreLite stores across invocations:
 
   rbd.py --data-dir /tmp/c1 mkpool rbd 3
   rbd.py --data-dir /tmp/c1 create rbd/disk --size 64M
+  rbd.py --data-dir /tmp/c1 mkpool ecdata --ec-k 2 --ec-m 1
+  rbd.py --data-dir /tmp/c1 create rbd/vm --size 1G --data-pool ecdata
   rbd.py --data-dir /tmp/c1 ls rbd
   rbd.py --data-dir /tmp/c1 info rbd/disk
   rbd.py --data-dir /tmp/c1 import rbd/disk ./disk.img
@@ -87,7 +89,11 @@ async def cmd_create(args) -> int:
         layout = FileLayout(stripe_unit=args.stripe_unit,
                             stripe_count=args.stripe_count,
                             object_size=args.object_size)
-        await rbd.create(name, _size(args.size), layout)
+        data_pool = (_rados._pool_id(_rados._load_pools(args.data_dir),
+                                     args.data_pool)
+                     if args.data_pool else None)
+        await rbd.create(name, _size(args.size), layout,
+                         data_pool=data_pool)
         print(f"image '{name}' created ({_size(args.size)} bytes)")
     finally:
         await c.stop()
@@ -112,6 +118,9 @@ async def cmd_info(args) -> int:
         st = await img.stat()
         for k, v in st.items():
             print(f"{k}: {v}")
+        if img.data_pool_id != img.pool_id:
+            pool = c.client.osdmap.pools[img.data_pool_id]
+            print(f"data_pool: {pool.name}")
         await img.release_lock()
     finally:
         await c.stop()
@@ -301,6 +310,8 @@ def main(argv=None) -> int:
     p.add_argument("--stripe-unit", type=int, default=1 << 16)
     p.add_argument("--stripe-count", type=int, default=4)
     p.add_argument("--object-size", type=int, default=1 << 22)
+    p.add_argument("--data-pool",
+                   help="pool for the data objects (e.g. an EC pool)")
     p.set_defaults(fn=cmd_create)
 
     p = sub.add_parser("ls")
