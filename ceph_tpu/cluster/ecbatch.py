@@ -11,9 +11,17 @@ This module closes that gap NIC-interrupt-coalescing style:
   tick. An mClock-aware fast-flush keeps latency honest: when the op
   scheduler reports nothing else queued that could contribute stripes,
   waiting out the window is pure added latency and the batch goes now.
+- **One queue per event loop.** The batchers of every OSD on one
+  running loop (an in-process cluster's 12 daemons) share its queues:
+  a bucket gathers the small stripes of all of them into one dispatch
+  instead of one each. Each queued stripe group remembers the batcher
+  that submitted it (its owner), so waits, the idle probe, ``close``
+  and the ``ec_batch`` fault site stay per owner, and each dispatch
+  counts on one owner's perf. A process with one OSD has one owner.
 - **Double buffering.** While one batch is in flight on the executor,
   the next accumulates; completion drains it immediately, so the
-  in-flight time itself is the accumulation window under load.
+  in-flight time itself is the accumulation window under load. A
+  queue that reaches the size target goes at once, in flight or not.
 - **Fused encode+CRC.** The device path dispatches ONE program that
   returns parity cells AND the per-cell CRC32Cs of data+parity (the
   bench's fused_stacked trick in the data path) — no second host pass
@@ -58,6 +66,7 @@ import asyncio
 import os
 import threading
 import time
+import weakref
 
 import numpy as np
 
@@ -139,6 +148,33 @@ def _worker_call(fn, *args):
     return out, clock, t_start, time.perf_counter_ns()
 
 
+class _LoopQueues:
+    """The dispatch queues of one event loop, shared by every ECBatcher
+    that submits on it."""
+
+    __slots__ = ("pending", "timers", "scheduled", "inflight")
+
+    def __init__(self) -> None:
+        #: bucket key -> [(codec, cells, fut, t_enqueue, owner)]
+        self.pending: dict[tuple, list] = {}
+        #: bucket key -> (reason, TimerHandle) for an armed flush timer
+        self.timers: dict[tuple, tuple] = {}
+        self.scheduled: set[tuple] = set()
+        #: bucket key -> dispatches in flight (no entry when none)
+        self.inflight: dict[tuple, int] = {}
+
+
+#: running loop -> its queues; a finished loop's queues go with it
+_QUEUES: weakref.WeakKeyDictionary = weakref.WeakKeyDictionary()
+
+
+def _loop_queues(loop) -> _LoopQueues:
+    q = _QUEUES.get(loop)
+    if q is None:
+        q = _QUEUES[loop] = _LoopQueues()
+    return q
+
+
 def codec_profile_key(codec) -> tuple:
     """Stable bucket identity of a codec: exactly the fields that
     determine its generator matrix and execution engine. ``id(codec)``
@@ -167,12 +203,6 @@ class ECBatcher:
 
     def __init__(self, perf=None, conf=None, idle_probe=None,
                  fault=None) -> None:
-        #: bucket key -> [(codec, cells, fut, t_enqueue)]
-        self._pending: dict[tuple, list] = {}
-        #: bucket key -> (reason, TimerHandle) for an armed flush timer
-        self._timers: dict[tuple, tuple] = {}
-        self._scheduled: set[tuple] = set()
-        self._inflight: set[tuple] = set()
         #: ops currently parked on a batcher future (queued OR riding
         #: an in-flight dispatch) — the daemon's idle probe compares
         #: this against its op-tracker to tell "everyone who could
@@ -186,6 +216,8 @@ class ECBatcher:
         #: optional FaultInjector (the owning OSD's): site "ec_batch"
         #: fails a dispatch, exercising the fail-closed isolation path
         self.fault = fault
+        #: (devices, width) of the configured serving mesh, or None
+        self._mesh_shape = self._configured_mesh()
         #: serving-mesh resolution state: resolved lazily on the first
         #: device-engine dispatch (jax/device init must not ride the
         #: daemon constructor) and cached; a platform that cannot
@@ -243,6 +275,9 @@ class ECBatcher:
                              "batched EC decode dispatches")
         perf.add_histogram("ec_decode_stripes",
                            "stripes per EC decode batch")
+        perf.add_histogram("ec_batch_osds",
+                           "distinct OSDs whose stripes rode one "
+                           "successful EC encode or decode dispatch")
         perf.add_histogram("ec_queue_wait_us",
                            "per-stripe-group wait in the batch queue (us)")
         # one sample per successful dispatch (host_lat on either
@@ -308,23 +343,26 @@ class ECBatcher:
             return "off"
         return mode if mode in ("allgather", "psum_bits") else "off"
 
+    def _configured_mesh(self) -> tuple | None:
+        n = w = 0
+        if self.conf is not None:
+            try:
+                n = int(self.conf["osd_ec_mesh_devices"])
+                w = int(self.conf["osd_ec_mesh_width"])
+            except Exception:
+                n = 0
+        return (n, max(1, w)) if n > 1 else None
+
     def mesh(self):
         """The serving mesh this batcher stages onto, or None (single-
         device path). Resolved once from the osd_ec_mesh_* knobs via
         parallel/runtime.py — the process-level cache means every OSD
         in a test cluster shares one mesh, like chips on a host."""
         if not self._mesh_resolved:
-            n = w = 0
-            if self.conf is not None:
-                try:
-                    n = int(self.conf["osd_ec_mesh_devices"])
-                    w = int(self.conf["osd_ec_mesh_width"])
-                except Exception:
-                    n = 0
-            if n > 1:
+            if self._mesh_shape is not None:
                 from ..parallel import runtime
 
-                self._mesh_cached = runtime.serving_mesh(n, max(1, w))
+                self._mesh_cached = runtime.serving_mesh(*self._mesh_shape)
             self._mesh_resolved = True
         return self._mesh_cached
 
@@ -368,7 +406,8 @@ class ECBatcher:
         return await self._submit(key, codec, cells)
 
     def parked(self) -> int:
-        """Ops currently awaiting a batcher future (see _parked).
+        """Ops of this batcher's owner currently awaiting a batcher
+        future (see _parked).
 
         Counts BOTH client encode/decode waits and background
         (recovery/scrub) decode waits — the idle probe compares this
@@ -379,20 +418,34 @@ class ECBatcher:
         still bound both."""
         return self._parked
 
+    @property
+    def _inflight(self) -> dict:
+        """Dispatches in flight per bucket on the running loop, of
+        every batcher that shares its queues."""
+        return _loop_queues(asyncio.get_running_loop()).inflight
+
     def close(self) -> None:
-        """Daemon shutdown: cancel armed flush timers/scheduled flushes
-        and fail every queued waiter so nothing fires into a stopped
-        daemon or hangs a caller. In-flight executor batches finish on
-        their own; their completion drain finds the queues empty."""
-        for _, handle in self._timers.values():
-            handle.cancel()
-        self._timers.clear()
-        self._scheduled.clear()
-        pending, self._pending = self._pending, {}
-        for items in pending.values():
-            for _, _, fut, _ in items:
-                if not fut.done():
-                    fut.set_result(_FAILED)
+        """Daemon shutdown: take this batcher's queued stripes out of
+        the shared queues and fail their waiters, so nothing hangs a
+        caller; other batchers' stripes stay queued, and a bucket left
+        empty loses its armed flush timer. In-flight executor batches
+        finish on their own, this batcher's stripes in them included."""
+        for q in list(_QUEUES.values()):
+            for key, items in list(q.pending.items()):
+                mine = [it for it in items if it[4] is self]
+                if not mine:
+                    continue
+                rest = [it for it in items if it[4] is not self]
+                if rest:
+                    q.pending[key] = rest
+                else:
+                    del q.pending[key]
+                    timer = q.timers.pop(key, None)
+                    if timer is not None:
+                        timer[1].cancel()
+                for _, _, fut, _, _ in mine:
+                    if not fut.done():
+                        fut.set_result(_FAILED)
 
     async def _submit(self, key: tuple, codec, cells: np.ndarray):
         # cells pass through AS A VIEW (the zero-copy staging contract:
@@ -403,12 +456,18 @@ class ECBatcher:
         # copy; forcing contiguity here would re-buy the transpose copy
         # this layout exists to kill
         loop = asyncio.get_running_loop()
+        q = _loop_queues(loop)
+        # where the dispatch runs is part of the bucket, so a mesh
+        # batcher and a single-device one never share a batch (the
+        # engine is the profile's backend, which "auto" resolves once
+        # per process)
+        key += (self._mesh_shape,)
         fut = loop.create_future()
-        self._pending.setdefault(key, []).append(
-            (codec, cells, fut, loop.time()))
+        q.pending.setdefault(key, []).append(
+            (codec, cells, fut, loop.time(), self))
         self._parked += 1
         try:
-            self._poke(key)
+            self._poke(q, key)
             result = await fut
         finally:
             self._parked -= 1
@@ -418,78 +477,110 @@ class ECBatcher:
 
     # ---------------------------------------------------- flush policy
 
-    def _poke(self, key: tuple, drain: bool = False) -> None:
+    @staticmethod
+    def _owners(items: list) -> list:
+        """The distinct owners of queued items, in queue order."""
+        return list(dict.fromkeys(it[4] for it in items))
+
+    def _full(self, items: list) -> bool:
+        """The queued stripes reached the size target (0: no target)."""
+        target = self._target_stripes()
+        return target > 0 and sum(len(it[1]) for it in items) >= target
+
+    def _poke(self, q: _LoopQueues, key: tuple,
+              drain: bool = False) -> None:
         """Decide whether the bucket flushes now, later, or not yet."""
-        queue = self._pending.get(key)
-        if not queue or key in self._scheduled:
+        queue = q.pending.get(key)
+        if not queue:
             return
-        if key in self._inflight:
+        if self._full(queue):
+            # a full batch waits for no one: not for a batch in flight,
+            # and not for the next tick, where the other OSDs' full
+            # batches of the same tick would join it and every op in
+            # the merged batch would wait for all of it
+            self._flush(q, key, "size")
+            return
+        if key in q.scheduled or key in q.inflight:
             return  # double-buffer: accumulate; completion drains us
         if drain:
-            self._arm_now(key, "drain")
-            return
-        target = self._target_stripes()
-        if target > 0 and sum(len(c) for _, c, _, _ in queue) >= target:
-            self._arm_now(key, "size")
+            self._arm_now(q, key, "drain")
             return
         window = self._window()
         if window <= 0:
-            self._arm_now(key, "tick")
+            self._arm_now(q, key, "tick")
             return
-        armed = self._timers.get(key)
-        if self.idle_probe is not None and self.idle_probe():
-            # nothing else queued that could contribute stripes: do NOT
-            # wait out the window — but settle for a few ms first, so a
-            # cohort still in client transit (invisible to the op
-            # tracker until it arrives) can land in the same batch
-            # (adaptive interrupt coalescing, not a bare fast path).
-            # An already-armed fast timer stays: re-arming on every
-            # arrival would defer the flush unboundedly.
+        armed = q.timers.get(key)
+        if all(o.idle_probe is not None and o.idle_probe()
+               for o in self._owners(queue)):
+            # no owner aboard has anything else queued that could
+            # contribute stripes: do NOT wait out the window — but
+            # settle for a few ms first, so a cohort still in client
+            # transit (invisible to the op tracker until it arrives)
+            # can land in the same batch (adaptive interrupt
+            # coalescing, not a bare fast path). An already-armed fast
+            # timer stays: re-arming on every arrival would defer the
+            # flush unboundedly.
             if armed is None or armed[0] == "deadline":
                 if armed is not None:
                     armed[1].cancel()
                 settle = min(window * 0.1, 0.005)
-                self._timers[key] = ("fast",
-                                     asyncio.get_running_loop().call_later(
-                                         settle, self._flush, key, "fast"))
+                q.timers[key] = ("fast",
+                                 asyncio.get_running_loop().call_later(
+                                     settle, self._flush, q, key, "fast"))
             return
         if armed is None:
-            self._timers[key] = ("deadline",
-                                 asyncio.get_running_loop().call_later(
-                                     window, self._flush, key, "deadline"))
+            q.timers[key] = ("deadline",
+                             asyncio.get_running_loop().call_later(
+                                 window, self._flush, q, key, "deadline"))
 
-    def _arm_now(self, key: tuple, reason: str) -> None:
+    def _arm_now(self, q: _LoopQueues, key: tuple, reason: str) -> None:
         """Flush on the next tick (coalesces same-tick submissions)."""
-        self._scheduled.add(key)
-        asyncio.get_running_loop().call_soon(self._flush, key, reason)
+        q.scheduled.add(key)
+        asyncio.get_running_loop().call_soon(self._flush, q, key, reason)
 
-    def _flush(self, key: tuple, reason: str) -> None:
-        self._scheduled.discard(key)
-        timer = self._timers.pop(key, None)
+    def _flush(self, q: _LoopQueues, key: tuple, reason: str) -> None:
+        q.scheduled.discard(key)
+        timer = q.timers.pop(key, None)
         if timer is not None:
             timer[1].cancel()
-        items = self._pending.pop(key, None)
+        items = q.pending.pop(key, None)
         if not items:
             return
-        if key in self._inflight:
-            # a deadline fired while the drain path held the bucket:
-            # put the work back; completion will drain it
-            self._pending.setdefault(key, [])[:0] = items
+        if key in q.inflight and not self._full(items):
+            # a deadline fired while a batch held the bucket: put the
+            # work back; completion will drain it
+            q.pending.setdefault(key, [])[:0] = items
             return
-        self._inflight.add(key)
-        if self.perf is not None:
-            self.perf.inc(f"ec_flush_{reason}")
-        asyncio.get_running_loop().create_task(self._run(key, items))
+        q.inflight[key] = q.inflight.get(key, 0) + 1
+        # the oldest stripes' owner runs the dispatch and counts it
+        lead = items[0][4]
+        if lead.perf is not None:
+            lead.perf.inc(f"ec_flush_{reason}")
+        asyncio.get_running_loop().create_task(lead._run(q, key, items))
+
+    def _release(self, q: _LoopQueues, key: tuple) -> None:
+        """A dispatch of the bucket is off the executor: drop its
+        in-flight mark and drain what queued behind it."""
+        n = q.inflight[key] - 1
+        if n:
+            q.inflight[key] = n
+        else:
+            del q.inflight[key]
+        self._poke(q, key, drain=True)
 
     # ------------------------------------------------------- execution
+
+    def _injected(self, key: tuple, stripes: int) -> bool:
+        """This owner's armed ``ec_batch`` fault site fires."""
+        return self.fault is not None and self.fault.hit(
+            "ec_batch", kind=key[0], stripes=stripes)
 
     async def _dispatch_once(self, loop, key: tuple, codec,
                              cells: np.ndarray):
         """One executor dispatch of a cell batch (shared by the normal
         batched path and the per-item isolation retries); the armed
         ``ec_batch`` fault site fails it with an InjectedError."""
-        if self.fault is not None and self.fault.hit(
-                "ec_batch", kind=key[0], stripes=len(cells)):
+        if self._injected(key, len(cells)):
             raise InjectedError("injected EC batch dispatch failure")
         if key[0] == "enc":
             fn, args = self._encode_sync, (codec, cells)
@@ -513,52 +604,65 @@ class ECBatcher:
                           if isinstance(exc, InjectedError)
                           else "ec_batch_failures_dispatch")
 
+    def _count_dispatch(self, kind: str, stripes: int, owners: int) -> None:
+        """Throughput counters of one successful dispatch."""
+        if self.perf is None:
+            return
+        if kind == "enc":
+            self.perf.inc("ec_batches")
+            self.perf.observe("ec_batch_stripes", stripes)
+        else:
+            self.perf.inc("ec_decode_batches")
+            self.perf.observe("ec_decode_stripes", stripes)
+        self.perf.observe("ec_batch_osds", owners)
+
     async def _fail_closed(self, loop, key: tuple, items: list,
                            batch_exc: BaseException) -> None:
         """Fail closed: a poisoned batch must fail ONLY the stripes of
         the ops that still fail alone. Each submission group is retried
-        as its own dispatch, so one op's bad stripes never reject its
-        batch-mates, every waiter resolves exactly once, and the
-        coalescing queue keeps flowing (callers never hold a PG lock
-        across batcher awaits, so no lock can leak either way)."""
-        kind = key[0]
-        for codec, cells, fut, _t0 in items:
+        as its own dispatch of its owner, against its owner's fault
+        site, so one op's bad stripes never reject its batch-mates,
+        every waiter resolves exactly once, and the coalescing queue
+        keeps flowing (callers never hold a PG lock across batcher
+        awaits, so no lock can leak either way)."""
+        for codec, cells, fut, _t0, owner in items:
             if fut.done():
                 continue
             if len(items) == 1:
                 # alone in the batch: the batch failure IS this op's
-                self._count_cause(batch_exc)
+                owner._count_cause(batch_exc)
                 fut.set_result(_FAILED)
                 continue
             try:
-                out = await self._dispatch_once(loop, key, codec, cells)
+                out = await owner._dispatch_once(loop, key, codec, cells)
             except Exception as e:
-                self._count_cause(e)
+                owner._count_cause(e)
                 fut.set_result(_FAILED)
                 continue
-            if self.perf is not None:
-                self.perf.inc("ec_batch_isolated")
-                if kind == "enc":
-                    self.perf.inc("ec_batches")
-                    self.perf.observe("ec_batch_stripes", len(cells))
-                else:
-                    self.perf.inc("ec_decode_batches")
-                    self.perf.observe("ec_decode_stripes", len(cells))
+            if owner.perf is not None:
+                owner.perf.inc("ec_batch_isolated")
+            owner._count_dispatch(key[0], len(cells), 1)
             fut.set_result(out)
 
-    async def _run(self, key: tuple, items: list) -> None:
+    async def _run(self, q: _LoopQueues, key: tuple, items: list) -> None:
         loop = asyncio.get_running_loop()
-        if self.perf is not None:
-            now = loop.time()
-            for _, _, _, t0 in items:
-                self.perf.observe("ec_queue_wait_us",
-                                  max(0.0, (now - t0) * 1e6))
+        now = loop.time()
+        for _, _, _, t0, owner in items:
+            if owner.perf is not None:
+                owner.perf.observe("ec_queue_wait_us",
+                                   max(0.0, (now - t0) * 1e6))
         kind = key[0]
         codec = items[0][0]
         cells = (items[0][1] if len(items) == 1
-                 else np.concatenate([c for _, c, _, _ in items]))
+                 else np.concatenate([it[1] for it in items]))
+        owners = self._owners(items)
         released = False
         try:
+            # the armed fault site of any owner aboard fails the batch
+            # (this batcher's own fires in _dispatch_once)
+            if any(o._injected(key, len(cells))
+                   for o in owners if o is not self):
+                raise InjectedError("injected EC batch dispatch failure")
             out = await self._dispatch_once(loop, key, codec, cells)
         except Exception as e:
             # failed dispatches are NOT throughput: count the failure
@@ -569,29 +673,21 @@ class ECBatcher:
             # the serial isolation retries grind through the wreck —
             # and release exactly ONCE: by the time _fail_closed
             # returns, a fresh batch for this key may be in flight,
-            # and discarding its marker would let a third _run launch
-            # concurrently.
+            # and a second release would drop its mark and let a third
+            # _run launch concurrently.
             if self.perf is not None:
                 self.perf.inc("ec_batch_failures")
             released = True
-            self._inflight.discard(key)
-            self._poke(key, drain=True)
+            self._release(q, key)
             await self._fail_closed(loop, key, items, e)
             return
         finally:
             if not released:
-                self._inflight.discard(key)
-                self._poke(key, drain=True)
+                self._release(q, key)
         # perf accounting strictly after success
-        if self.perf is not None:
-            if kind == "enc":
-                self.perf.inc("ec_batches")
-                self.perf.observe("ec_batch_stripes", len(cells))
-            else:
-                self.perf.inc("ec_decode_batches")
-                self.perf.observe("ec_decode_stripes", len(cells))
+        self._count_dispatch(kind, len(cells), len(owners))
         row = 0
-        for _, c, fut, _ in items:
+        for _, c, fut, _, _ in items:
             b = len(c)
             if not fut.done():
                 if kind == "enc":
