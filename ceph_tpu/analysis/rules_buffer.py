@@ -36,6 +36,7 @@ from .core import Finding, Rule, register
 #: scope until it earns a seam
 _CLUSTER_HOT = (
     "ceph_tpu/cluster/pg.py",
+    "ceph_tpu/cluster/ec_backend.py",
     "ceph_tpu/cluster/client.py",
     "ceph_tpu/cluster/osd.py",
     "ceph_tpu/cluster/messages.py",

@@ -20,7 +20,7 @@ parent class layouts.  ``BufferList`` payloads stay in the data
 plane; only summaries cross the control pipe.  Flags any
 ``pickle``/``cPickle``/``marshal`` use on the fabric surfaces
 (``msg/``, ``cluster/procstart.py``, ``cluster/daemon.py``,
-``tools/swarm.py``, ``bench.py``).
+``tools/swarm.py``).
 
 ``fabric-shm-release`` — every shm ring consume path must release
 its descriptors: a function that drains ``recv_all()`` and never
@@ -29,7 +29,7 @@ producer's free list starves (backpressure masquerading as a hang).
 The idiomatic form copies out and releases in ``finally``.
 
 Scope: ``ceph_tpu/msg/``, ``ceph_tpu/cluster/``, ``ceph_tpu/utils/``,
-``tools/``, ``bench.py`` — the layers the fabric traverses.
+``tools/`` — the layers the fabric traverses.
 """
 from __future__ import annotations
 
@@ -39,10 +39,10 @@ from typing import Iterator
 from .core import Finding, Rule, ScopedVisitor, call_name, register
 
 _SCOPES = ("ceph_tpu/msg/", "ceph_tpu/cluster/", "ceph_tpu/utils/",
-           "tools/", "bench.py")
+           "tools/")
 
 _PIPE_SURFACES = ("ceph_tpu/msg/", "cluster/procstart.py",
-                  "cluster/daemon.py", "tools/swarm.py", "bench.py")
+                  "cluster/daemon.py", "tools/swarm.py")
 
 
 def _match(path: str, prefixes) -> bool:

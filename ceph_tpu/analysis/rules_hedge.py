@@ -4,8 +4,8 @@ The cluster tier's EC sub-read fan-outs route through the shared
 hedged-fanout helper (cluster/hedge.py): first-sufficient-subset
 completion, EWMA-delayed extras, loser cancellation, and the
 ``ec_hedges_*`` counter ledger. A bare ``asyncio.gather`` over
-``await_reply`` / ``_fetch_shard_copy`` calls re-introduces the
-wait-for-the-slowest seam the hedging pass removed — byte-identical
+``await_reply`` / ``_sub_read`` / ``_fetch_shard`` calls re-introduces
+the wait-for-the-slowest seam the hedging pass removed — byte-identical
 results, silently tail-dominated latency, and no counters to show for
 it. The write fan-outs are all-ack (every participant must land) and
 legitimately gather; only the first-k read/reconstruct seams are in
@@ -32,7 +32,7 @@ _SCOPE = "ceph_tpu/cluster/"
 
 #: reply-wait callees that mark a first-k completion seam: a gather
 #: over these waits for the SLOWEST shard of a subset-decodable read
-_REPLY_WAITS = frozenset(("await_reply", "_fetch_shard_copy"))
+_REPLY_WAITS = frozenset(("await_reply", "_sub_read", "_fetch_shard"))
 
 _SPAWNERS = frozenset(("create_task", "ensure_future"))
 

@@ -22,10 +22,9 @@ The rule flags, inside ``ceph_tpu/parallel/`` and the batcher module
 Sanctioned boundaries, by function name: the per-device view reader
 (``shard_rows_to_host``), the counted gather (``host_gather``), the
 single-device engine boundary the batcher already owns
-(``_encode_sync`` / ``_decode_sync`` and the ``_dispatch_block``
-row-block closures of the over-decomposed dispatch — their mesh
-siblings are NOT sanctioned, they must route through the view
-reader), and the host-side helper that touches device lists, not
+(``_encode_sync`` / ``_decode_sync`` and their ``_dispatch_block``
+closures — their mesh siblings are NOT sanctioned, they must route
+through the view reader), and the host-side helper that touches device lists, not
 data (``make_mesh``).
 """
 from __future__ import annotations

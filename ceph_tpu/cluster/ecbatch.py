@@ -99,9 +99,7 @@ COLD_SHAPE_BYTES = 256 << 20
 
 class _StageClock:
     """Worker-thread stage times (ns) of one dispatch, flushed into the
-    batcher's time_avg counters once per dispatch on the loop. Over-
-    decomposed row blocks add here from several threads at once
-    (list.append is atomic), so their sums are thread time."""
+    batcher's time_avg counters once per dispatch on the loop."""
 
     __slots__ = ("host", "device")
 
@@ -254,17 +252,6 @@ class ECBatcher:
         perf.add_u64_counter("ec_mesh_decode_dispatches",
                              "decode/repair dispatches run as mesh "
                              "collectives (parallel_repair_mode)")
-        perf.add_u64_counter("ec_overdecompose_rounds",
-                             "decode/repair dispatches run rateless-"
-                             "over-decomposed into row-block sub-tasks")
-        perf.add_u64_counter("ec_overdecompose_subtasks",
-                             "row-block sub-task copies dispatched by "
-                             "over-decomposed rounds (primary + hedge "
-                             "duplicate per block)")
-        perf.add_u64_counter("ec_overdecompose_shed",
-                             "stale sub-task copies shed (cancelled, "
-                             "or landed after their block had already "
-                             "resolved)")
         perf.add_u64_counter("ec_decode_cold_host",
                              "decode/repair rounds dispatched on the "
                              "host engine because their survivor "
@@ -317,14 +304,6 @@ class ECBatcher:
             return float(self.conf["osd_ec_batch_window"])
         except Exception:
             return 0.0
-
-    def _overdecompose_factor(self) -> int:
-        if self.conf is None:
-            return 0
-        try:
-            return int(self.conf["osd_ec_overdecompose"])
-        except Exception:
-            return 0
 
     def _cold_shape_bytes(self) -> int:
         if self.conf is None:
@@ -816,78 +795,6 @@ class ECBatcher:
             self.perf.inc("ec_mesh_encode_dispatches")
         return out
 
-    def _overdecomposed(self, cells: np.ndarray, run):
-        """Rateless recovery over-decomposition (arXiv:1804.10331) —
-        the device-tier half of straggler-proof dispatch. The batched
-        recovery matmul splits along its batch axis into
-        ``osd_ec_overdecompose`` x workers row blocks (rs.row_blocks);
-        every block is dispatched TWICE across a bounded worker pool
-        (primary + one hedge duplicate), the first copy per block to
-        land wins, and stale copies are shed — so a straggling worker
-        (slow chip, contended core) sheds work instead of gating the
-        round. Byte-exact by construction: both copies of a block run
-        the SAME kernel over the SAME rows, and the blocks partition
-        the batch. Returns None when the knob is off or the batch is
-        too small to split (the legacy single dispatch)."""
-        factor = self._overdecompose_factor()
-        n = len(cells)
-        if factor <= 0 or n < 2:
-            return None
-        import concurrent.futures as cf
-
-        from ..ops import rs
-
-        devs = getattr(self.mesh(), "devices", None)
-        workers = int(getattr(devs, "size", 0) or 0)
-        if workers <= 0:
-            workers = min(4, os.cpu_count() or 1)
-        blocks = rs.row_blocks(n, factor * workers)
-        if len(blocks) <= 1:
-            return None
-        if self.perf is not None:
-            self.perf.inc("ec_overdecompose_rounds")
-            self.perf.inc("ec_overdecompose_subtasks", 2 * len(blocks))
-        results: list = [None] * len(blocks)
-        remaining = [2] * len(blocks)
-        shed = 0
-        with cf.ThreadPoolExecutor(max_workers=workers) as pool:
-            futs = {}
-            for i, (lo, hi) in enumerate(blocks):
-                for _copy in range(2):
-                    futs[pool.submit(run, cells[lo:hi])] = i
-            pending = set(futs)
-            try:
-                while pending:
-                    done, pending = cf.wait(
-                        pending, return_when=cf.FIRST_COMPLETED)
-                    for f in done:
-                        i = futs[f]
-                        remaining[i] -= 1
-                        if results[i] is not None:
-                            shed += 1  # landed after its twin won
-                            continue
-                        try:
-                            results[i] = f.result()
-                        except Exception:
-                            # one copy of a block failing is survivable
-                            # (its twin may land); both failing is the
-                            # dispatch failure — propagate it and let
-                            # _run's fail-closed isolation take over
-                            if remaining[i] == 0:
-                                raise
-                    if all(r is not None for r in results):
-                        # every pending copy is now stale: cancelled if
-                        # unstarted, else drained by pool shutdown with
-                        # its result discarded — shed either way
-                        shed += len(pending)
-                        break
-            finally:
-                for f in pending:
-                    f.cancel()
-        if self.perf is not None and shed:
-            self.perf.inc("ec_overdecompose_shed", shed)
-        return np.concatenate(results)
-
     def _cold_shape(self, key: tuple, nbytes: int, warm) -> bool:
         """True while a decode/repair survivor pattern is still cold —
         the cold-shape shield. Device decode kernels specialize per
@@ -994,11 +901,9 @@ class ECBatcher:
             mode = self._repair_mode()
             if (mesh is not None and mode != "off"
                     and hasattr(codec, "decode_batch_mesh")):
-                # the collective path already distributes ONE matmul
-                # across every chip with its own combine — slicing its
-                # batch would serialize collectives, so it keeps its
-                # own distribution and skips over-decomposition (and
-                # the cold-shape shield: mesh rounds are storm-sized)
+                # the collective path distributes ONE matmul across
+                # every chip with its own combine, and skips the
+                # cold-shape shield: mesh rounds are storm-sized
                 return self._mesh_decode_sync(codec, present, want,
                                               cells, mesh, mode)
             import jax
@@ -1026,11 +931,8 @@ class ECBatcher:
                                                  kp, su, clock)
                 if self.perf is not None:
                     self.perf.inc("ec_decode_cold_host")
-                out = self._overdecomposed(cells, shield)
-                return out if out is not None else shield(cells)
-        out = self._overdecomposed(cells, _dispatch_block)
-        return (out if out is not None
-                else _dispatch_block(cells))
+                return shield(cells)
+        return _dispatch_block(cells)
 
     def _repair_sync(self, codec, present: tuple, want: tuple,
                      cells: np.ndarray) -> np.ndarray:
@@ -1070,11 +972,8 @@ class ECBatcher:
                                                  clock)
                 if self.perf is not None:
                     self.perf.inc("ec_decode_cold_host")
-                out = self._overdecomposed(cells, shield)
-                return out if out is not None else shield(cells)
-        out = self._overdecomposed(cells, _dispatch_block)
-        return (out if out is not None
-                else _dispatch_block(cells))
+                return shield(cells)
+        return _dispatch_block(cells)
 
     def _mesh_decode_sync(self, codec, present: tuple, want: tuple,
                           cells: np.ndarray, mesh,

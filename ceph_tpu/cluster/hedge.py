@@ -28,14 +28,12 @@ Counter ledger (owned by the calling OSD's perf counters):
 - ``ec_hedges_wasted_bytes`` — payload bytes of completed hedges the
   winning subset did not need (the bandwidth price of the tail cut)
 
-``CEPH_TPU_HEDGE=0`` is the A/B lever: it forces plan-exact fan-outs
-(no extras) without touching per-daemon config, so a bench can run
-hedged and unhedged arms in one process tree.
+``osd_hedge_reads = False`` turns hedging off: plan-exact fan-outs, no
+extras.
 """
 from __future__ import annotations
 
 import asyncio
-import os
 from typing import Awaitable, Callable, Iterable
 
 #: candidate: (key, peer osd id, zero-arg factory -> awaitable outcome)
@@ -43,10 +41,7 @@ Candidate = tuple[object, int, Callable[[], Awaitable]]
 
 
 def hedge_enabled(conf=None) -> bool:
-    """The A/B lever: env wins (CEPH_TPU_HEDGE=0 forces off), then the
-    ``osd_hedge_reads`` knob, then on."""
-    if os.environ.get("CEPH_TPU_HEDGE", "") == "0":
-        return False
+    """The ``osd_hedge_reads`` knob; on without a conf."""
     if conf is not None:
         try:
             return bool(conf["osd_hedge_reads"])

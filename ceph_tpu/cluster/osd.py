@@ -352,8 +352,8 @@ class OSDLite:
             fut.set_result(value)
 
     def hedge_enabled(self) -> bool:
-        """Straggler-proof read fan-outs armed? (knob AND the
-        CEPH_TPU_HEDGE env A/B lever — see cluster/hedge.py)."""
+        """Straggler-proof read fan-outs armed? (the osd_hedge_reads
+        knob — see cluster/hedge.py)."""
         from .hedge import hedge_enabled
 
         return hedge_enabled(self.conf)
@@ -767,13 +767,13 @@ class OSDLite:
         elif isinstance(msg, M.MECSubWrite):
             pg = self._ensure_pg(msg.pgid, msg.shard)
             with self.tracer.start_span("ec_sub_write", parent=msg.trace):
-                await pg.handle_ec_write(src, msg)
+                await pg.ec.handle_write(src, msg)
         elif isinstance(msg, M.MECSubWriteReply):
             self._resolve(msg.tid, msg)
         elif isinstance(msg, M.MECSubRead):
             pg = self._ensure_pg(msg.pgid, msg.shard)
             with self.tracer.start_span("ec_sub_read", parent=msg.trace):
-                await pg.handle_ec_read(src, msg)
+                await pg.ec.handle_read(src, msg)
         elif isinstance(msg, M.MECSubReadReply):
             self._resolve(msg.tid, msg)
         elif isinstance(msg, M.MPGInfoReq):

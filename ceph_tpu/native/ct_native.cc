@@ -2,7 +2,7 @@
 //
 // This is the C++ "jerasure role" of the framework (SURVEY.md §7): the
 // bit-exactness oracle for the JAX/TPU kernels and the honest CPU baseline
-// for bench.py's vs_baseline ratio. It replaces the reference's vendored
+// they are measured against. It replaces the reference's vendored
 // math submodules (gf-complete/jerasure, ISA-L, crc32c asm — see
 // SURVEY.md §2.4, empty in the reference checkout) with a self-contained
 // implementation: scalar table paths everywhere, plus SSSE3/AVX2 nibble-

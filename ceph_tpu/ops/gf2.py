@@ -163,8 +163,7 @@ def gf2_apply_np_blocked(plan: np.ndarray, rows: np.ndarray,
     """Batch-blocked host apply: byte-identical to ``gf2_apply_np``,
     but the (..., R, terms, L) gather intermediate is materialized at
     most ``block`` stripes at a time — a recovery storm's host decode
-    keeps bounded scratch instead of scaling it with the batch, and
-    the over-decomposed dispatch's row blocks reuse the same grain."""
+    keeps bounded scratch instead of scaling it with the batch."""
     if rows.ndim < 3 or len(rows) <= block:
         return gf2_apply_np(plan, rows)
     return np.concatenate([gf2_apply_np(plan, rows[i:i + block])

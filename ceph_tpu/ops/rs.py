@@ -524,25 +524,6 @@ def encode_np(matrix: np.ndarray, data: np.ndarray) -> np.ndarray:
     return gf8.gf_matmul(matrix, data)
 
 
-def row_blocks(n: int, parts: int) -> list[tuple[int, int]]:
-    """Balanced contiguous partition of an ``n``-row batch axis into
-    at most ``parts`` non-empty ``(lo, hi)`` blocks — the rateless
-    over-decomposition grain of the batched recovery matmul
-    (arXiv:1804.10331): schedule more sub-tasks than workers so a
-    straggling worker sheds blocks to its peers instead of gating the
-    round. Block sizes differ by at most one row, so a pow2-padded
-    dispatch sees at most two compiled shapes per round."""
-    parts = max(1, min(parts, n))
-    base, extra = divmod(n, parts)
-    blocks: list[tuple[int, int]] = []
-    lo = 0
-    for i in range(parts):
-        hi = lo + base + (1 if i < extra else 0)
-        blocks.append((lo, hi))
-        lo = hi
-    return blocks
-
-
 def pack_u32(chunks_bytes: np.ndarray) -> np.ndarray:
     """(..., L) uint8 with L % 4 == 0 -> (..., L/4) uint32 little-endian."""
     a = np.ascontiguousarray(chunks_bytes, dtype=np.uint8)

@@ -1,8 +1,8 @@
 """JAX persistent compilation cache, placeable from outside.
 
-Every entry point that drives the chip (``chip_smoke.py``, ``bench.py``,
-a ``--platform default`` daemon) calls :func:`enable` before its first
-compile. The directory is ``$JAX_COMPILATION_CACHE_DIR`` when the
+Every entry point that drives the chip (``chip_smoke.py``, the
+benchmark harness, a ``--platform default`` daemon) calls
+:func:`enable` before its first compile. The directory is ``$JAX_COMPILATION_CACHE_DIR`` when the
 environment sets it — JAX reads that variable itself, so no other
 directory is set in code — and otherwise one fixed path inside the
 checkout. The path is part of every cache key, so it is never derived

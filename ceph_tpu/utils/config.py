@@ -234,8 +234,7 @@ SCHEMA = Schema([
                 "shard reconstructs fan sub-reads out to d > k "
                 "candidates, complete on the first decodable subset "
                 "and cancel the losers (first-sufficient-subset "
-                "hedging); the CEPH_TPU_HEDGE=0 env lever forces it "
-                "off for A/B runs"),
+                "hedging)"),
     Option("osd_hedge_delay_factor", "float", 2.0, min=1.0,
            desc="hedge trigger multiplier over the per-peer sub-op "
                 "latency EWMA: extra candidates launch after factor x "
@@ -247,14 +246,6 @@ SCHEMA = Schema([
            desc="hedge width: extra shard candidates (beyond the "
                 "minimal decode plan) a single fan-out may launch "
                 "(0 = plan-exact fan-out, hedging off)"),
-    Option("osd_ec_overdecompose", "int", 0, min=0,
-           desc="recovery-matmul over-decomposition factor: >0 splits "
-                "each batched decode/repair dispatch into factor x "
-                "workers row-block sub-tasks dispatched redundantly, "
-                "first result per block wins — a slow worker sheds "
-                "its block instead of gating the round (rateless "
-                "over-decomposition stance; 0 = one dispatch per "
-                "batch, the legacy path)"),
     Option("osd_ec_cold_shape_bytes", "size", 256 << 20, min=0,
            desc="cold-shape shield threshold: a decode/repair survivor "
                 "pattern dispatches on the host engine until its "

@@ -47,12 +47,12 @@ N_OBJECTS = 256
 N_DEGRADED = 64
 INFLIGHT = 16
 N_OSDS = 12
-#: data shards lost by the degraded phase (as bench.py's ERASED)
+#: data shards lost by the degraded phase
 ERASED = (1, 6)
 
 PROFILE = {"plugin": "rs_tpu", "k": str(K), "m": str(M),
            "backend": "device"}
-#: the batching knobs of bench config 6; the cold-shape shield is off
+#: the EC batching knobs; the cold-shape shield is off
 #: so every decode round of the degraded phase takes the device
 OSD_CONF = {
     "osd_ec_batch_window": 0.01,

@@ -3,7 +3,7 @@
 Tests run on the CPU: all sharding/collective tests use 8 virtual CPU
 devices, mirroring how the reference tests cluster logic without a
 cluster (MemStore / vstart tiers, SURVEY.md §4). The chip is driven by
-`chip_smoke.py` and `bench.py` through the chip tool, never by tests
+`chip_smoke.py` and `benchmark/run.py`, never by tests
 (tests/test_tpu_compile.py only compiles for a described v5e).
 
 pin_virtual_cpu must run before the first jax backend init (importing jax

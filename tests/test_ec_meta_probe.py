@@ -138,7 +138,7 @@ def test_missing_object_still_probes_and_keeps_peer_xattrs():
         t_rm.remove(pg.cid, b"held")
         pg.osd.store.queue_transaction(t_rm)
         pg.missing[b"held"] = version
-        assert not pg._absent_on_own_shard(b"held")
+        assert not pg.ec.absent_on_own_shard(b"held")
         spy = SubReadSpy(c.bus)
         p0, l0 = meta_counts(c)
         outs = await c.client.operate(
@@ -168,7 +168,7 @@ def test_torn_shard_without_size_still_probes():
             t_touch.create_collection(pg.cid)
         t_touch.touch(pg.cid, b"torn")
         pg.osd.store.queue_transaction(t_touch)
-        assert not pg._absent_on_own_shard(b"torn")
+        assert not pg.ec.absent_on_own_shard(b"torn")
         p0, l0 = meta_counts(c)
         await c.client.write_full(POOL, "torn", data)
         p1, l1 = meta_counts(c)
@@ -185,15 +185,15 @@ def test_only_an_active_primary_decides_alone():
     async def t():
         c = await make_ec()
         pg = primary_pg(c, b"x")
-        assert pg._absent_on_own_shard(b"x")
+        assert pg.ec.absent_on_own_shard(b"x")
         replicas = [p for o in c.osds if o is not None
                     for p in o.pgs.values()
                     if p.pgid == pg.pgid and not p.is_primary()]
         assert replicas
-        assert not any(p._absent_on_own_shard(b"x") for p in replicas)
+        assert not any(p.ec.absent_on_own_shard(b"x") for p in replicas)
         pg.state = "peering"
         try:
-            assert not pg._absent_on_own_shard(b"x")
+            assert not pg.ec.absent_on_own_shard(b"x")
         finally:
             pg.state = "active"
         await c.stop()

@@ -19,6 +19,7 @@ from ceph_tpu.cluster import TestCluster
 from ceph_tpu.cluster import messages as M
 from ceph_tpu.cluster.faults import (FaultPlane, NetFaultPolicy,
                                      Thrasher, build_schedule, flip_bit)
+from ceph_tpu.cluster.ec_backend import ECBackend
 from ceph_tpu.cluster.pg import ATTR_V, PG, UNFOUND_GRACE
 from ceph_tpu.placement.osdmap import Pool
 from ceph_tpu.store import transaction as tx
@@ -239,7 +240,7 @@ def test_stale_shard_read_version_crosscheck():
 
         # seed read path: trusts per-shard hinfo only -> mixes stale
         # and new cells -> wrong bytes (or a reconstruct error)
-        PG._ec_version_check = False
+        ECBackend._version_check = False
         try:
             try:
                 got = await c.client.read(2, "obj")
@@ -247,7 +248,7 @@ def test_stale_shard_read_version_crosscheck():
             except (IOError, KeyError):
                 pass  # "cannot reconstruct" is the other seed symptom
         finally:
-            PG._ec_version_check = True
+            ECBackend._version_check = True
 
         # hardened path: version-lagging shards excluded, bytes exact
         assert await c.client.read(2, "obj") == v2
@@ -844,20 +845,20 @@ def test_unfound_grace_anchors_on_recovery_progress():
 
 @pytest.mark.slow
 def test_slow_recovery_keeps_acked_writes(monkeypatch):
-    """ROADMAP item (d) regression: delaying _reconstruct_chunk by
+    """ROADMAP item (d) regression: delaying the shard rebuild by
     ~80 ms per call (a saturated device link / cold-compile shape)
     made the 20 s seeded thrash lose an acked generation ~1-in-3 on
     plain rs at seed 20260803 — UNFOUND_GRACE expired while recovery
     was still grinding, the skip converged heads over the gap, and
     scrub rolled the orphan back. With the grace anchored on recovery
     progress the same run stays byte-exact."""
-    orig = PG._reconstruct_chunk
+    orig = ECBackend.rebuild
 
     async def slow_reconstruct(self, oid, shard):
         await asyncio.sleep(0.08)
         return await orig(self, oid, shard)
 
-    monkeypatch.setattr(PG, "_reconstruct_chunk", slow_reconstruct)
+    monkeypatch.setattr(ECBackend, "rebuild", slow_reconstruct)
 
     async def t():
         seed = 20260803
@@ -911,7 +912,7 @@ def test_late_subop_pg_shell_never_wedges_wait_clean():
 
 
 def test_primary_delta_write_over_missing_base_bounces():
-    """Review-found sibling of the handle_ec_write missing-base bounce:
+    """Review-found sibling of the handle_write missing-base bounce:
     the PRIMARY's own shard used to apply a delta write even when its
     base content was on the missing record (head converged over a
     skipped unfound push), stamping the new ATTR_V + copied hinfo over

@@ -1,14 +1,14 @@
-"""Straggler-proof dispatch (ISSUE 18): hedged EC fan-outs with loser
-cancellation, per-peer EWMA hedge delays, rateless over-decomposition
-of batched recovery matmuls, the slow-OSD fault arm, and the seeded
-straggler thrash.
+"""Straggler-proof dispatch: hedged EC fan-outs with loser
+cancellation, per-peer EWMA hedge delays, the batched decode and
+repair dispatches the hedged reads feed, the slow-OSD fault arm, and
+the seeded straggler thrash.
 
 The contract under test: hedging changes WHEN bytes arrive, never
 WHICH bytes — hedged reads are byte-exact vs unhedged under injected
 stragglers, cancelled losers leak neither tasks nor reply
 expectations (``ec_hedges_canceled == fired - won`` by construction),
-and the over-decomposed device dispatch is bit-identical to the
-legacy single dispatch for every (k, m, erasure) draw.
+and the batched device dispatch is bit-identical to the codec for
+every (k, m, erasure) draw.
 """
 import asyncio
 import random
@@ -100,11 +100,13 @@ def test_one_straggler_cannot_postpone_the_hedge():
     assert e.hedge_delay([1, 2, 3, 4, 5]) == pytest.approx(0.05)
 
 
-def test_hedge_enabled_env_lever(monkeypatch):
-    monkeypatch.delenv("CEPH_TPU_HEDGE", raising=False)
+def test_hedge_enabled_env_lever():
+    """Hedging follows the osd_hedge_reads knob, and is on where no
+    conf says otherwise."""
     assert hedge_enabled(None)
-    monkeypatch.setenv("CEPH_TPU_HEDGE", "0")
-    assert not hedge_enabled(None)
+    assert hedge_enabled({})
+    assert hedge_enabled({"osd_hedge_reads": True})
+    assert not hedge_enabled({"osd_hedge_reads": False})
 
 
 # ------------------------------------------------ hedged_fanout unit
@@ -187,13 +189,12 @@ def test_hedged_fanout_cancels_unfinished_hedges():
     run(t(), timeout=30)
 
 
-def test_hedged_fanout_env_off_is_plan_exact(monkeypatch):
-    """CEPH_TPU_HEDGE=0 (the A/B lever): extras never launch, no
-    hedge counters move — the legacy plan-exact fan-out."""
-    monkeypatch.setenv("CEPH_TPU_HEDGE", "0")
-
+def test_hedged_fanout_env_off_is_plan_exact():
+    """osd_hedge_reads = False: extras never launch, no hedge counters
+    move — the plan-exact fan-out."""
     async def t():
         osd = _FakeOsd(delay=0.0)
+        osd.conf = {"osd_hedge_reads": False}
         log = []
         out = await hedged_fanout(
             osd,
@@ -225,13 +226,11 @@ def test_hedged_fanout_records_exceptions_as_outcomes():
 # ------------------------------- hedged read vs stragglers (cluster)
 
 
-def test_hedged_read_byte_exact_and_leak_free(monkeypatch):
+def test_hedged_read_byte_exact_and_leak_free():
     """Under a persistently slow OSD, hedged EC reads return the exact
     written bytes, route around the straggler (hedges fire AND win),
     cancel losers without leaking reply expectations, and the unhedged
-    A/B arm (CEPH_TPU_HEDGE=0) reads the same bytes the slow way."""
-    monkeypatch.delenv("CEPH_TPU_HEDGE", raising=False)
-
+    arm (osd_hedge_reads = False) reads the same bytes the slow way."""
     async def t():
         c = await make_ec_cluster(seed=7)
         try:
@@ -261,20 +260,19 @@ def test_hedged_read_byte_exact_and_leak_free(monkeypatch):
                     break
                 await asyncio.sleep(0.1)
             assert all(not o.pending for o in c.osds if o is not None)
-            # A/B arm: unhedged reads the same bytes, just without
-            # firing hedges
-            monkeypatch.setenv("CEPH_TPU_HEDGE", "0")
+            # unhedged arm: the same bytes, just without firing hedges
+            for o in c.osds:
+                o.conf.set("osd_hedge_reads", False)
             fired0 = hedge_totals(c)["ec_hedges_fired"]
             for name, data in payloads.items():
                 assert await c.client.read(2, name) == data
             assert hedge_totals(c)["ec_hedges_fired"] == fired0
         finally:
-            monkeypatch.delenv("CEPH_TPU_HEDGE", raising=False)
             await c.stop()
     run(t(), timeout=240)
 
 
-# --------------------------- device tier: rateless over-decomposition
+# ------------------------------- device tier: the batched dispatches
 
 
 def _conf(**kw):
@@ -319,22 +317,24 @@ class _BatchPerf:
 
 
 def test_overdecompose_decode_parity_random_draws():
-    """First-sufficient over-decomposed decode is bit-identical to the
-    legacy full-round dispatch across random (k, m, erasure) draws on
-    the host engine, and the sub-task ledger balances: every block
-    resolves once, its hedge duplicate is shed."""
+    """The one batched decode dispatch on the device engine is
+    bit-identical to the host engine across random (k, m, erasure)
+    draws, and both rebuild the erased data rows exactly."""
     async def t():
         rng = np.random.default_rng(20260806)
+        # shield off: every round takes the engine it was asked for
+        batcher = ECBatcher(perf=None,
+                            conf=_conf(osd_ec_cold_shape_bytes=0))
         for trial in range(5):
             k = int(rng.integers(2, 6))
             m = int(rng.integers(1, 4))
-            codec = load_codec({"plugin": "rs_tpu", "k": str(k),
-                                "m": str(m), "backend": "host"})
-            su = _su_for(codec)
+            prof = {"plugin": "rs_tpu", "k": str(k), "m": str(m)}
+            host = load_codec({**prof, "backend": "host"})
+            dev = load_codec({**prof, "backend": "device"})
+            su = _su_for(host)
             b = int(rng.integers(9, 48))
             cells = rng.integers(0, 256, (b, k, su), dtype=np.uint8)
-            legacy = ECBatcher(perf=None, conf=_conf())
-            parity, _ = await legacy.encode_cells(codec, cells)
+            parity, _ = await batcher.encode_cells(host, cells)
             every = np.concatenate([cells, parity], axis=1)
             # erase a random data row (plus up to m-1 others), decode
             # the erased data from exactly k survivors
@@ -344,30 +344,21 @@ def test_overdecompose_decode_parity_random_draws():
                 rng.choice(others, size=k, replace=False).tolist()))
             want = tuple(j for j in range(k) if j not in present)
             surv = np.ascontiguousarray(every[:, list(present), :])
-            base = await legacy.decode_cells(codec, present, want, surv)
-            perf = _BatchPerf()
-            ECBatcher.declare_counters(perf)
-            od = ECBatcher(perf=perf,
-                           conf=_conf(osd_ec_overdecompose=3))
-            got = await od.decode_cells(codec, present, want, surv)
+            base = await batcher.decode_cells(host, present, want, surv)
+            got = await batcher.decode_cells(dev, present, want, surv)
             np.testing.assert_array_equal(
                 base, got, err_msg=f"trial {trial} k={k} m={m} "
                                    f"present={present}")
             for i, j in enumerate(want):
                 np.testing.assert_array_equal(got[:, i, :],
                                               cells[:, j, :])
-            d = perf.c
-            assert d["ec_overdecompose_rounds"] >= 1
-            # ledger: used-once-per-block + shed == submitted copies
-            assert d["ec_overdecompose_subtasks"] == \
-                2 * d["ec_overdecompose_shed"]
     run(t(), timeout=120)
 
 
 def test_overdecompose_repair_parity_clay():
-    """The sub-chunk repair kind rides the same over-decomposed
-    dispatch: bandwidth-optimal Clay repair through row blocks is
-    byte-identical to the single dispatch."""
+    """The batched sub-chunk repair dispatch rebuilds the same bytes as
+    the codec's own per-stripe Clay repair from the same helper
+    slices — and those are the lost chunk's cells."""
     async def t():
         codec = load_codec({"plugin": "clay", "k": "3", "m": "2",
                             "backend": "host"})
@@ -379,7 +370,6 @@ def test_overdecompose_repair_parity_clay():
         lost = 0
         avail = sorted(set(range(5)) - {lost})
         assert codec.is_repair({lost}, set(avail))
-        legacy = ECBatcher(perf=None, conf=_conf())
         plan = codec.minimum_to_decode([lost], avail)
         sub = su // codec.get_sub_chunk_count()
         order = sorted(plan)
@@ -388,11 +378,14 @@ def test_overdecompose_repair_parity_clay():
             np.concatenate([every[:, ch, o * sub:(o + cnt) * sub]
                             for o, cnt in runs], axis=1)
             for ch in order], axis=1)
-        base = await legacy.repair_cells(codec, tuple(order), (lost,),
+        batcher = ECBatcher(perf=None, conf=_conf())
+        got = await batcher.repair_cells(codec, tuple(order), (lost,),
                                          surv)
-        od = ECBatcher(perf=None, conf=_conf(osd_ec_overdecompose=2))
-        got = await od.repair_cells(codec, tuple(order), (lost,), surv)
-        np.testing.assert_array_equal(base, got)
+        ref = np.stack([
+            codec.repair([lost], {ch: surv[s, i]
+                                  for i, ch in enumerate(order)})[lost]
+            for s in range(len(surv))])
+        np.testing.assert_array_equal(got[:, 0, :], ref)
         np.testing.assert_array_equal(got[:, 0, :], every[:, lost, :])
     run(t(), timeout=120)
 
@@ -490,18 +483,18 @@ def test_hedge_fanout_rule_flags_gather_over_reply_waits():
         return await asyncio.gather(
             *(osd.await_reply(t, f, o) for t, f, o in waits))
     """
-    fs = lint(bad, "ceph_tpu/cluster/pg.py",
+    fs = lint(bad, "ceph_tpu/cluster/ec_backend.py",
               only=["hedge-fanout-discipline"])
     assert len(fs) == 1 and "hedged_fanout" in fs[0].message
 
     bad2 = """
     import asyncio
 
-    async def reconstruct(self, need):
+    async def rebuild(self, need):
         return await asyncio.gather(
-            *(self._fetch_shard_copy(oid, j) for j in need))
+            *(self._sub_read(j, live[j], oid) for j in need))
     """
-    assert lint(bad2, "ceph_tpu/cluster/pg.py",
+    assert lint(bad2, "ceph_tpu/cluster/ec_backend.py",
                 only=["hedge-fanout-discipline"])
 
 
@@ -556,13 +549,12 @@ def test_hedge_task_rule_flags_orphaned_hedge_tasks():
 # ------------------------------------------- seeded straggler thrash
 
 
-def test_straggler_thrash_converges_with_hedges(monkeypatch):
+def test_straggler_thrash_converges_with_hedges():
     """Tier-1 straggler thrash: a ~5 s seeded schedule with up to two
     persistently slow OSDs under concurrent oracle writers converges
     byte-exact, the verdict's hedge ledger proves hedges fired AND won
     while the leak-free invariant holds, and the schedule replays
     draw-for-draw (legacy availability draws untouched)."""
-    monkeypatch.delenv("CEPH_TPU_HEDGE", raising=False)
 
     async def t():
         c = await make_ec_cluster(seed=4321)

@@ -151,7 +151,7 @@ def test_lazy_subop_fields_wire_roundtrip():
     corrupt shard sub-ops (round-5 zero-copy change)."""
     from ceph_tpu.cluster import messages as M
     from ceph_tpu.cluster.pglog import Entry
-    from ceph_tpu.cluster.pg import enc_entries
+    from ceph_tpu.cluster.osd_types import enc_entries
     from ceph_tpu.store import transaction as tx
 
     t = tx.Transaction()

@@ -271,8 +271,9 @@ def test_batcher_routes_cellwise_codecs():
 
 
 def test_slice_subruns_selects_per_cell():
-    from ceph_tpu.cluster.pg import (_pack_subruns, _slice_subruns,
-                                     _unpack_subruns)
+    from ceph_tpu.cluster.ec_backend import (_pack_subruns,
+                                             _slice_subruns,
+                                             _unpack_subruns)
 
     codec = load_codec({"plugin": "clay", "k": "4", "m": "2"})
     subs = codec.get_sub_chunk_count()  # 8

@@ -1,9 +1,9 @@
 """Pallas GF(2^8) matmul kernel: bit-exactness vs the host byte oracle.
 
-The real kernel runs on TPU; under the CPU test mesh it runs in Pallas
-interpreter mode — same jaxpr, same semantics, so a pass here plus the
-TPU-side bench guard (bench.py checks device parity vs the C++ core on
-the real chip) covers both halves.
+The real kernel targets the TPU; under the CPU test mesh it runs in
+Pallas interpreter mode — same jaxpr, same semantics. Nothing checks it
+on the chip yet: the serving path picks the SWAR kernel there (ROADMAP,
+Design).
 """
 import numpy as np
 import jax.numpy as jnp

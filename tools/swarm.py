@@ -267,8 +267,7 @@ async def run_swarm(*, clients: int = 2000, duration: float = 8.0,
                     thrash_secs: float = 0.0,
                     qos: dict | None = None) -> dict:
     """Drive the swarm against a fresh in-process cluster and return
-    the measured payload (bench config 10's body and the tier-1
-    swarm tests' engine)."""
+    the measured payload (the tier-1 swarm tests' engine)."""
     from ceph_tpu.cluster.vstart import TestCluster
     from ceph_tpu.placement.osdmap import Pool
     from ceph_tpu.utils import config as cfg
