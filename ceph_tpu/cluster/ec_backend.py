@@ -108,6 +108,18 @@ def _slice_subruns(chunk: bytes, su: int, subruns: bytes,
         .reshape(-1)).toreadonly()
 
 
+def _stripe_runs(tlist: list[int]) -> list[tuple[int, int]]:
+    """[(a, b)] index ranges of ``tlist`` that one shard write each
+    carries: runs of consecutive stripe numbers."""
+    ncol = len(tlist)
+    if not ncol:
+        return []
+    if tlist[-1] - tlist[0] == ncol - 1:
+        return [(0, ncol)]  # one run: a whole object, or one stripe
+    cut = (np.flatnonzero(np.diff(tlist) != 1) + 1).tolist()
+    return list(zip([0] + cut, cut + [ncol]))
+
+
 class HinfoError(IOError):
     """A chunk failed its stored per-cell hinfo CRC (bit rot) — kept
     distinct from plain EIO so the read path can count it
@@ -373,6 +385,7 @@ class ECBackend:
                     read_bytes += end - start
 
         tlist = sorted(touched)
+        ncol = len(tlist)
         # Shard-major device STAGING buffer (the bufferlist seam of the
         # RMW path): rows are shard files — (k+m, T, su), data rows
         # first. The batcher consumes the data rows' (T, k, su)
@@ -384,7 +397,7 @@ class ECBackend:
         # transactions, and the store lands them at its own commit
         # boundary. A zero cell's CRC equals zero_cell_crc, so no
         # special-casing.
-        staging = np.zeros((n, len(tlist), si.su), dtype=np.uint8)
+        staging = np.zeros((n, ncol, si.su), dtype=np.uint8)
         data_sh = staging[:k]                      # (k, T, su)
         par_sh = staging[k:]                       # (m, T, su)
         if tlist:
@@ -405,8 +418,7 @@ class ECBackend:
                 # device engine: the per-cell hash_info CRCs came back
                 # from the SAME fused dispatch as the parity — no
                 # second pass over the encoded cells on the host
-                crc_d = np.ascontiguousarray(fused[:, :k].T)   # (k, T)
-                crc_p = np.ascontiguousarray(fused[:, k:].T)   # (m, T)
+                crcs = fused.T                             # (k+m, T)
             else:
                 # host engine: ONE multithreaded native CRC batch over
                 # the whole shard-major staging (same bytes the old
@@ -414,13 +426,16 @@ class ECBackend:
                 nthr = _os.cpu_count() or 1
                 crcs = native.crc32c_batch(
                     staging.reshape(-1, si.su), threads=nthr
-                ).reshape(n, len(tlist))
-                crc_d, crc_p = crcs[:k], crcs[k:]
-            nz = staging.any(axis=2)               # (k+m, T)
-            nz_d, nz_p = nz[:k], nz[k:]
+                ).reshape(n, ncol)
+            # the CRC patch table of every shard at once: row g is
+            # shard g's (stripe, crc) pairs, each hpatch a view of it
+            patch = np.empty((n, ncol, 2), dtype="<u4")
+            patch[:, :, 0] = tlist
+            patch[:, :, 1] = crcs
+        # every shard writes every touched cell, in the same runs
+        runs = _stripe_runs(tlist)
         shard_txns: dict[int, tx.Transaction] = {}
         hpatches: dict[int, bytes] = {}
-        cells_written = 0
         for g in range(n):
             pos = codec.chunk_index(g)
             cid = self.shard_cid(pos)
@@ -430,37 +445,17 @@ class ECBackend:
                 t.rmattrs(cid, oid)
             if not st8.exists0:
                 t.touch(cid, oid)
+            # contiguous staging views, not tobytes copies
+            for a, b in runs:
+                t.write(cid, oid, tlist[a] * si.su, staging[g, a:b])
             if new_nst != old_nst:
-                # shrink drops cells; grow zero-fills (parity of zero
-                # data is zero for these linear codes, so zero cells
-                # are already consistent codewords)
+                # after the writes, so a store extends the file only
+                # past what they wrote: a shrink drops cells, a grow
+                # zero-fills the stripes no write reached (parity of
+                # zero data is zero for these linear codes, so zero
+                # cells are already consistent codewords), and a
+                # longer stale copy is cut to the new end
                 t.truncate(cid, oid, new_nst * si.su)
-            patch = np.zeros((len(tlist), 2), dtype="<u4")
-            if tlist:
-                rows = staging[g]  # (T, su) contiguous shard rows
-                crc_g = crc_d[g] if g < k else crc_p[g - k]
-                nz_g = nz_d[g] if g < k else nz_p[g - k]
-            run_i = run_s = prev_s = -1
-            for i, s in enumerate(tlist):
-                # zero cell: covered by truncate zero-fill when the
-                # file grew past it; otherwise must be written
-                skip = (not nz_g[i]) and s >= old_nst
-                patch[i] = (s, crc_g[i])
-                if skip or (run_i >= 0 and s != prev_s + 1):
-                    if run_i >= 0:
-                        # contiguous staging view, not a tobytes copy
-                        t.write(cid, oid, run_s * si.su,
-                                rows[run_i:i])
-                        cells_written += i - run_i
-                        run_i = -1
-                if not skip:
-                    if run_i < 0:
-                        run_i, run_s = i, s
-                    prev_s = s
-            if run_i >= 0:
-                t.write(cid, oid, run_s * si.su,
-                        rows[run_i:len(tlist)])
-                cells_written += len(tlist) - run_i
             for m_ in st8.xattr_muts:
                 if m_[0] == "set":
                     t.setattr(cid, oid, USER_ATTR + m_[1], m_[2])
@@ -480,12 +475,12 @@ class ECBackend:
             # T=0 (xattr-only mutation) stays b"" — memoryview.cast
             # rejects zero-sized shapes, and "no patch" is the wire
             # contract for untouched data anyway
-            hpatches[pos] = (memoryview(patch).toreadonly().cast("B")
-                             if patch.size else b"")
+            hpatches[pos] = (memoryview(patch[g]).toreadonly().cast("B")
+                             if ncol else b"")
         # the byte counters move together, so their ratios hold in
         # any window
         osd.perf.inc("ec_user_bytes_written", user_bytes)
-        osd.perf.inc("ec_shard_bytes_written", cells_written * si.su)
+        osd.perf.inc("ec_shard_bytes_written", n * ncol * si.su)
         osd.perf.inc("ec_rmw_read_bytes", read_bytes)
         await self.fanout(oid, entries, shard_txns, hpatch=hpatches,
                           ncells=new_nst, size=new_size, live=live,
